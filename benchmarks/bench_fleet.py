@@ -97,7 +97,9 @@ def _metrics_rows(results) -> dict:
 
 
 def run_child(mode: str, smoke: bool) -> None:
-    """One cold measurement in this (fresh) process; JSON on the last line."""
+    """One cold measurement in this (fresh) process; JSON on the last line,
+    stamped with this process's own machine metadata.  The persistent
+    compilation cache stays off: the compile tax is what this measures."""
     from repro.core import flow
     from repro.core.arch import Constraints
 
@@ -137,6 +139,7 @@ def run_child(mode: str, smoke: bool) -> None:
     )
     assert _metrics_rows(results) == _metrics_rows(results2)
     print(json.dumps({
+        "machine": machine_metadata(),
         "mode": mode,
         "n_workloads": len(works),
         "cold_wall_s": round(cold_wall, 6),
@@ -191,7 +194,7 @@ def main() -> None:
     record = {
         "bench": "fleet",
         "smoke": args.smoke,
-        "machine": machine_metadata(),
+        "machine": rows["fleet"]["machine"],
         "metric_note": (
             "cold_wall_s = first multi-model sweep in a fresh process "
             "(includes XLA compilation); steady_wall_s = the same sweep "

@@ -27,6 +27,7 @@ import time
 import numpy as np
 
 from machine_meta import machine_metadata
+from repro.compile_cache import enable_compile_cache
 from repro.core import fusion, metrics as M
 from repro.core.ir import encoder_decoder_ir, residual_block_ir, resnet18_ir
 
@@ -125,6 +126,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="one measured rep per case (CI)")
     args = ap.parse_args()
+    enable_compile_cache(ROOT)
     reps = 2 if args.smoke else 5
 
     rb = residual_block_ir()

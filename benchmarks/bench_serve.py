@@ -135,6 +135,7 @@ def run_child(qps: float, n: int, faults: bool) -> None:
 
     stats = svc.stats()
     print(json.dumps({
+        "machine": machine_metadata(),
         "qps_offered": qps,
         "faults": faults,
         "n_requests": n,
@@ -202,6 +203,7 @@ def run_child_async(qps: float, n: int) -> None:
         key = f"ok:{r.rung or 'cache'}" if r.ok else r.error_type
         outcomes[key] = outcomes.get(key, 0) + 1
     print(json.dumps({
+        "machine": machine_metadata(),
         "qps_offered": qps,
         "n_requests": n,
         "achieved_qps": round(n / wall, 2),
@@ -251,6 +253,7 @@ def run_child_cancel(rounds: int) -> None:
         assert resp.error_type == "RequestCancelled", resp.error_type
     svc.shutdown(drain=True, timeout=300)
     print(json.dumps({
+        "machine": machine_metadata(),
         "rounds": rounds,
         "chunk_stall_seconds": inj.chunk_stall_seconds,
         "hw_chunk": 2,
@@ -305,6 +308,7 @@ def run_child_recover(n: int) -> None:
     rec.close()
     _, records = journal_mod.load(tmp)
     print(json.dumps({
+        "machine": machine_metadata(),
         "n_requests": n,
         "wal_records": len(records),
         "responses_restored": restored,
@@ -330,6 +334,9 @@ def main() -> None:
                     help="(internal) child measurement family")
     args = ap.parse_args()
     if args.n:  # child processes always carry --n
+        from repro.compile_cache import enable_compile_cache
+
+        enable_compile_cache(ROOT)
         if args.mode == "paced":
             run_child(args.qps, args.n, args.faults)
         elif args.mode == "async":
@@ -415,7 +422,7 @@ def main() -> None:
     record = {
         "bench": "serve",
         "smoke": args.smoke,
-        "machine": machine_metadata(),
+        "machine": rows[0]["machine"],
         "metric_note": (
             "Paced request stream at each offered QPS in a fresh process "
             "(one warmup plan, then the timed stream).  Latency is "

@@ -296,6 +296,10 @@ def main() -> None:
     ap.add_argument("--devices", type=int,
                     help="(internal) run one cold measurement in-process")
     args = ap.parse_args()
+    if args.devices or args.faults:
+        from repro.compile_cache import enable_compile_cache
+
+        enable_compile_cache(ROOT)
     if args.devices:
         run_child(args.devices, args.smoke)
         return
@@ -323,12 +327,14 @@ def main() -> None:
                "--devices", str(d)]
         if args.smoke:
             cmd.append("--smoke")
-        # Inherit the full environment: a minimal env drops JAX_PLATFORMS
-        # and libtpu then probes GCP instance metadata for minutes.
+        # This benchmark measures the sharding contract on d virtual CPU
+        # devices (forced host platform), so each child runs on the CPU
+        # backend on purpose, also on a machine with a TPU.  The sharded
+        # sweep on real chips is `python chip_smoke.py --chips 4`.
         env = {
             **os.environ,
             "XLA_FLAGS": f"--xla_force_host_platform_device_count={d}",
-            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+            "JAX_PLATFORMS": "cpu",
         }
         proc = subprocess.run(cmd, capture_output=True, text=True, cwd=ROOT,
                               env=env)
@@ -361,7 +367,7 @@ def main() -> None:
         d: round(rows[d]["candidates_per_s"] / base["candidates_per_s"], 2)
         for d in DEVICE_COUNTS
     }
-    machine = machine_metadata()
+    machine = base["machine"]
     record = {
         "bench": "shard",
         "smoke": args.smoke,

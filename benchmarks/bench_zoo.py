@@ -32,7 +32,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import pathlib
 import subprocess
 import sys
@@ -90,9 +89,13 @@ def _cut_batches(graphs):
 
 
 def run_child(smoke: bool) -> None:
-    """The cold measurement in this (fresh) process; JSON on the last line."""
+    """The cold measurement in this (fresh) process; JSON on the last line,
+    stamped with this process's own machine metadata."""
+    from repro.compile_cache import enable_compile_cache
     from repro.core import flow
     from repro.core.arch import Constraints
+
+    enable_compile_cache(ROOT)
 
     loose = Constraints(*[float("inf")] * 4)
 
@@ -139,6 +142,7 @@ def run_child(smoke: bool) -> None:
         }
 
     print(json.dumps({
+        "machine": machine_metadata(),
         "n_configs": len(graphs),
         "trace_s": round(trace_s, 6),
         "search_s": round(search_s, 6),
@@ -165,12 +169,9 @@ def main() -> None:
     cmd = [sys.executable, str(pathlib.Path(__file__).resolve()), "--child"]
     if args.smoke:
         cmd.append("--smoke")
-    # Inherit the full environment (PR 6): a minimal env drops JAX_PLATFORMS
-    # and libtpu then probes GCP instance metadata for minutes.
-    env = {**os.environ,
-           "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
-    proc = subprocess.run(cmd, capture_output=True, text=True, cwd=ROOT,
-                          env=env)
+    # The child inherits the environment unchanged, so it sweeps on the
+    # backend this command was given; the parent never touches JAX.
+    proc = subprocess.run(cmd, capture_output=True, text=True, cwd=ROOT)
     if proc.returncode != 0:  # surface the child's traceback in CI logs
         sys.stderr.write(proc.stdout)
         sys.stderr.write(proc.stderr)
@@ -180,7 +181,6 @@ def main() -> None:
     record = {
         "bench": "zoo",
         "smoke": args.smoke,
-        "machine": machine_metadata(),
         "metric_note": (
             "One run_fleet program over the whole config zoo: each "
             "architecture's real forward pass traced to GraphIR at "

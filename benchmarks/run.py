@@ -5,12 +5,14 @@ human-readable block per table.  Usage: ``python -m benchmarks.run``.
 """
 from __future__ import annotations
 
+import pathlib
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import REGISTRY, SHAPES
 from repro.core import fusion, metrics as M
 from repro.core.arch import (
@@ -331,6 +333,7 @@ TABLES = [
 
 
 def main() -> None:
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1])
     print("name,us_per_call,derived")
     for t in TABLES:
         t()

@@ -27,7 +27,7 @@ import time
 from typing import Sequence
 
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from . import fusion
 from . import metrics as M
@@ -207,7 +207,7 @@ def _compiled_sweep(
     if exe is not None:
         return exe, 0.0
     t0 = time.perf_counter()
-    with enable_x64():
+    with enable_x64(True):
         exe = fn.lower(*args).compile()
     dt = time.perf_counter() - t0
     _sweep_cache_put(key, exe)
@@ -218,7 +218,7 @@ def _run_sweep(exe, args) -> tuple[np.ndarray, float]:
     """(result, sweep_seconds): one timed execution of an AOT executable
     (inside ``enable_x64`` — the executable's avals are float64)."""
     t1 = time.perf_counter()
-    with enable_x64():
+    with enable_x64(True):
         out = np.asarray(exe(*args))
     return out, time.perf_counter() - t1
 

@@ -43,9 +43,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import enable_x64
 
 from .errors import ConfigValidationError, GraphValidationError
-from jax.experimental import enable_x64
 
 from .arch import DLAConfig
 from .ir import GraphIR, NetworkIR, as_graph
@@ -668,7 +668,7 @@ def evaluate_batch_graph(
     oracles term for term); pre-converted float32 ``jnp`` arrays keep
     float32 semantics.
     """
-    with enable_x64():
+    with enable_x64(True):
         raw = _jit_batch_graph(
             feat, esrc, edst, ewords, src_mask, sink_mask, cuts_batch,
             hw_rows, area_consts, node_mask, edge_mask,
@@ -734,7 +734,7 @@ def sharded_fleet_kernel(mesh):
     the padded rows off before metrics composition — the PR 4 inert-padding
     idiom applied to the hardware axis).
     """
-    from ..parallel.sharding import HW_AXIS, mesh_fingerprint, shard_map_fn
+    from ..parallel.sharding import HW_AXIS, mesh_fingerprint
 
     key = mesh_fingerprint(mesh)
     fn = _SHARDED_FLEET_KERNELS.get(key)
@@ -743,7 +743,7 @@ def sharded_fleet_kernel(mesh):
 
         repl = P()
         fn = jax.jit(
-            shard_map_fn()(
+            jax.shard_map(
                 _evaluate_fleet_graph,
                 mesh=mesh,
                 in_specs=(repl,) * 7 + (P(HW_AXIS), repl, repl, repl),
@@ -829,7 +829,7 @@ def evaluate_fleet_graph(
 ) -> np.ndarray:
     """(G, H, C, 4) metrics — scoped-x64 wrapper over the jitted fleet
     kernel (see :func:`evaluate_batch_graph` for the dtype contract)."""
-    with enable_x64():
+    with enable_x64(True):
         raw = _jit_fleet_graph(
             feat, esrc, edst, ewords, src_mask, sink_mask, cuts_batch,
             hw_rows, area_consts, node_mask, edge_mask,

@@ -118,7 +118,7 @@ def _plan_attention(hd: int, seq: int, spec: TPUSpec):
     best = (MXU, MXU, vmem_bytes(MXU, MXU, hd))
     for bq in (128, 256, 512, 1024):
         for bk in (128, 256, 512, 1024):
-            if bq > seq or bk > seq:
+            if bq > seq or bk > seq or seq % bq or seq % bk:
                 continue
             b = vmem_bytes(bq, bk, hd)
             if b <= budget and bq * bk > best[0] * best[1]:
@@ -133,7 +133,7 @@ def _plan_mlp(d: int, ff: int, spec: TPUSpec):
     best = None
     for bm in (128, 256, 512):
         for bf in (128, 256, 512, 1024, 2048):
-            if bf > ff:
+            if bf > ff or ff % bf:  # the kernel tiles d_ff exactly
                 continue
             b = vmem_bytes(bm, bf, d)
             if b <= budget and (best is None or bm * bf > best[0] * best[1]):
@@ -143,11 +143,25 @@ def _plan_mlp(d: int, ff: int, spec: TPUSpec):
     return best
 
 
+def _plan_ssm(d_inner: int, d_state: int, spec: TPUSpec):
+    """Longest scan chunk whose working set fits VMEM/4 at
+    ``block_d = min(512, d_inner)`` (d_state pads to 128 lanes, so the
+    chunk is what the budget bounds)."""
+    from ..kernels.mamba_scan import vmem_bytes
+
+    block_d = min(512, d_inner)
+    for chunk in (64, 32, 16):
+        if vmem_bytes(chunk, block_d, d_state) <= spec.vmem_bytes // 4:
+            return chunk, block_d
+    return 8, block_d
+
+
 def plan_model(cfg, seq_len: int, spec: TPUSpec = TPU_V5E) -> FusionPlan:
     """Plan kernel tilings for one config and score fused vs lbl traffic."""
     hd = cfg.resolved_head_dim
     bq, bk, attn_b = _plan_attention(hd, seq_len, spec)
     bm, bf, mlp_b = _plan_mlp(cfg.d_model, max(cfg.d_ff, cfg.d_model), spec)
+    chunk, block_d = _plan_ssm(cfg.d_inner, cfg.ssm_state, spec)
 
     # Evaluator pass over one transformer block: fused vs layer-by-layer BW.
     # The block chain embeds as a GraphIR so the same edge-cut search that
@@ -170,9 +184,9 @@ def plan_model(cfg, seq_len: int, spec: TPUSpec = TPU_V5E) -> FusionPlan:
         mlp_block_m=bm,
         mlp_block_f=bf,
         mlp_vmem_bytes=mlp_b,
-        mamba_chunk=64,
-        mamba_block_d=min(512, cfg.d_inner),
-        conv_block_c=64,
+        mamba_chunk=chunk,
+        mamba_block_d=block_d,
+        conv_block_c=MXU,
         bw_fused_words=fused,
         bw_lbl_words=lbl,
         search_engine=engine,

@@ -1345,9 +1345,12 @@ class AsyncPlanningService:
     def _beat(self) -> None:
         self._last_beat = time.monotonic()
         if self.heartbeat_path is not None:
+            # Write-then-rename: a reader never sees a truncated beat.
+            tmp = f"{self.heartbeat_path}.tmp"
             try:
-                with open(self.heartbeat_path, "w") as f:
+                with open(tmp, "w") as f:
                     f.write(f"{os.getpid()} {time.time():.3f}\n")
+                os.replace(tmp, self.heartbeat_path)
             except OSError:  # liveness reporting must never kill serving
                 pass
 

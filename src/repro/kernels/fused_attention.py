@@ -10,8 +10,7 @@ and sequential; the running (m, l, acc) state persists in VMEM scratch
 across KV steps.  GQA is handled in the index maps (q head h reads kv head
 h // group).  Masking supports causal / sliding-window / chunked-local via
 absolute position arithmetic (full-block skipping is a real-TPU grid-
-pruning optimisation; here blocks are masked, which is what interpret mode
-validates).
+pruning optimisation; here blocks are masked).
 """
 from __future__ import annotations
 
@@ -22,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import VMEM_LIMIT_BYTES, interpret_default, tile_bytes
 
 NEG_INF = -1e30
 
@@ -85,9 +86,14 @@ def flash_attention(
     chunk: int = 0,  # chunked-local (attn_chunked); 0 = off
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Pallas flash attention.  Requires Sq % block_q == Skv % block_k == 0."""
+    """Pallas flash attention.  Requires Sq % block_q == Skv % block_k == 0.
+
+    ``interpret=None`` interprets on the CPU backend and compiles
+    elsewhere (:func:`repro.kernels.interpret_default`)."""
+    if interpret is None:
+        interpret = interpret_default(q, k, v)
     B, Sq, H, hd = q.shape
     Skv, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -128,17 +134,22 @@ def flash_attention(
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(qt, kt, vt)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
 
 
 def vmem_bytes(block_q: int, block_k: int, hd: int, dtype_bytes: int = 2) -> int:
-    """VMEM working set claimed by the BlockSpecs (planner feasibility)."""
+    """VMEM working set at these blocks (planner feasibility): the
+    pipelined q/k/v/out tiles twice (double buffering), plus the f32
+    score tile and the (m, l, acc) scratch, all in native tiling."""
     tiles = (
-        block_q * hd * dtype_bytes  # q block
-        + 2 * block_k * hd * dtype_bytes  # k, v blocks
-        + block_q * hd * dtype_bytes  # out block
-        + block_q * block_k * 4  # score tile (f32 vregs)
-        + block_q * (hd + 2) * 4  # acc + m + l scratch
+        2 * tile_bytes((block_q, hd), dtype_bytes)  # q, out blocks
+        + 2 * tile_bytes((block_k, hd), dtype_bytes)  # k, v blocks
     )
-    return tiles
+    return (
+        2 * tiles
+        + tile_bytes((block_q, block_k), 4)  # score tile
+        + 2 * tile_bytes((1, block_q), 4)  # m, l scratch
+        + tile_bytes((block_q, hd), 4)  # acc scratch
+    )

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import VMEM_LIMIT_BYTES, interpret_default, tile_bytes
+
 
 def _kernel(x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_sc, *, n_fblocks, act):
     jf = pl.program_id(1)
@@ -65,8 +67,10 @@ def fused_mlp(
     act: str = "swiglu",
     block_m: int = 128,
     block_f: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
+    if interpret is None:
+        interpret = interpret_default(x, w1, w2)
     T, d = x.shape
     ff = w1.shape[1]
     block_m = min(block_m, T)
@@ -90,15 +94,21 @@ def fused_mlp(
         out_shape=jax.ShapeDtypeStruct((T, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d), jnp.float32)],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(x, w1, w3, w2)
 
 
 def vmem_bytes(block_m: int, block_f: int, d: int, dtype_bytes: int = 2) -> int:
+    """VMEM working set at these blocks: the pipelined x/w1/w3/w2/out
+    tiles twice (double buffering), the f32 accumulator, and the f32
+    hidden tiles h and g."""
+    tiles = (
+        2 * tile_bytes((block_m, d), dtype_bytes)  # x, out tiles
+        + 2 * tile_bytes((d, block_f), dtype_bytes)  # w1, w3 tiles
+        + tile_bytes((block_f, d), dtype_bytes)  # w2 tile
+    )
     return (
-        block_m * d * dtype_bytes  # x tile
-        + 2 * d * block_f * dtype_bytes  # w1, w3 tiles
-        + block_f * d * dtype_bytes  # w2 tile
-        + 2 * block_m * block_f * 4  # h, g f32
-        + block_m * d * 4  # accumulator
-        + block_m * d * dtype_bytes  # out tile
+        2 * tiles
+        + tile_bytes((block_m, d), 4)  # accumulator
+        + 2 * tile_bytes((block_m, block_f), 4)  # h, g
     )

@@ -9,9 +9,9 @@ falcon-mamba's d_inner=8192, d_state=16.
 
 Grid: ``(B, d_inner/block_d, S/chunk)`` with the sequence axis innermost
 and sequential; the state carry lives in VMEM scratch, zero-initialised at
-chunk 0.  In-chunk steps run as a fori_loop (the associative-scan variant
-is the chunked pure-JAX path in repro.models.ssm; this kernel validates
-the memory-hierarchy layout in interpret mode).
+chunk 0.  In-chunk steps run as a fori_loop that reads step ``t`` of each
+block straight from VMEM and writes ``y_t`` in place (the associative-scan
+variant is the chunked pure-JAX path in repro.models.ssm).
 """
 from __future__ import annotations
 
@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import VMEM_LIMIT_BYTES, interpret_default, tile_bytes
+
 
 def _kernel(dA_ref, dBx_ref, c_ref, y_ref, h_sc, *, chunk):
     js = pl.program_id(2)
@@ -30,22 +32,17 @@ def _kernel(dA_ref, dBx_ref, c_ref, y_ref, h_sc, *, chunk):
     def _init():
         h_sc[...] = jnp.zeros_like(h_sc)
 
-    dA = dA_ref[0].astype(jnp.float32)  # (chunk, bd, ds)
-    dBx = dBx_ref[0].astype(jnp.float32)
-    c = c_ref[0].astype(jnp.float32)  # (chunk, ds)
+    def step(t, h):
+        h = dA_ref[0, t].astype(jnp.float32) * h + dBx_ref[0, t].astype(
+            jnp.float32
+        )  # (bd, ds)
+        c_t = c_ref[0, pl.ds(t, 1), :].astype(jnp.float32)  # (1, ds)
+        y_ref[0, pl.ds(t, 1), :] = jnp.sum(h * c_t, axis=1)[None, :].astype(
+            y_ref.dtype
+        )
+        return h
 
-    def step(t, carry):
-        h, ys = carry
-        h = dA[t] * h + dBx[t]  # (bd, ds)
-        y_t = jnp.sum(h * c[t][None, :], axis=1)  # (bd,)
-        ys = jax.lax.dynamic_update_index_in_dim(ys, y_t, t, 0)
-        return h, ys
-
-    h0 = h_sc[...]
-    ys0 = jnp.zeros((chunk, dA.shape[1]), jnp.float32)
-    h, ys = jax.lax.fori_loop(0, chunk, step, (h0, ys0))
-    h_sc[...] = h
-    y_ref[0] = ys.astype(y_ref.dtype)
+    h_sc[...] = jax.lax.fori_loop(0, chunk, step, h_sc[...])
 
 
 def selective_scan(
@@ -55,9 +52,12 @@ def selective_scan(
     *,
     chunk: int = 64,
     block_d: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Returns y (B, S, di) f32."""
+    """Returns y (B, S, di) f32.  ``interpret=None`` interprets on the CPU
+    backend and compiles elsewhere (:func:`repro.kernels.interpret_default`)."""
+    if interpret is None:
+        interpret = interpret_default(dA, dBx, C)
     B, S, di, ds = dA.shape
     chunk = min(chunk, S)
     block_d = min(block_d, di)
@@ -77,14 +77,17 @@ def selective_scan(
         out_shape=jax.ShapeDtypeStruct((B, S, di), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_d, ds), jnp.float32)],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(dA, dBx, C)
     return out
 
 
 def vmem_bytes(chunk: int, block_d: int, ds: int) -> int:
-    return (
-        2 * chunk * block_d * ds * 4  # dA, dBx tiles
-        + chunk * ds * 4  # C tile
-        + block_d * ds * 4  # state scratch
-        + chunk * block_d * 4  # y tile
+    """VMEM working set: the pipelined dA/dBx/C/y blocks twice (the
+    d_state minor dim pads to 128 lanes) plus the state scratch."""
+    tiles = (
+        2 * tile_bytes((chunk, block_d, ds), 4)  # dA, dBx tiles
+        + tile_bytes((chunk, ds), 4)  # C tile
+        + tile_bytes((chunk, block_d), 4)  # y tile
     )
+    return 2 * tiles + tile_bytes((block_d, ds), 4)  # + state scratch

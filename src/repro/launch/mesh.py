@@ -8,13 +8,7 @@ its first JAX import and gets the full meshes.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 names explicit/auto axis types; 0.4.x has neither
-    from jax.sharding import AxisType
-
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-except ImportError:  # pragma: no cover - exercised on jax 0.4.x only
-    _AXIS_KW = lambda n: {}  # noqa: E731
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,12 +19,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh (tests / elastic re-meshing)."""
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def single_device_mesh():

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharding import shard_map_unchecked
-
 
 def bubble_fraction(n_micro: int, stages: int) -> float:
     ticks = n_micro + stages - 1
@@ -48,9 +46,9 @@ def pipeline_apply(
     ticks = n_micro + stages - 1
 
     @partial(
-        shard_map_unchecked, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()),  # params split by stage; data replicated
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )
     def run(params, xm):
         stage = jax.lax.axis_index(axis)

@@ -34,48 +34,6 @@ TP = "model"
 HW_AXIS = "hardware"
 
 
-def shard_map_fn():
-    """The ``shard_map`` entry point, across jax versions.
-
-    jax >= 0.6 promotes it to ``jax.shard_map``; on 0.4.x it lives in
-    ``jax.experimental.shard_map``.  Same keyword signature
-    ``(f, mesh=..., in_specs=..., out_specs=...)`` either way.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm
-
-
-def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication/varying-manual-axes checking off,
-    across jax versions: the flag is ``check_vma`` on jax >= 0.6 and
-    ``check_rep`` on 0.4.x.  (The evaluator's uses are all statically
-    replicated, so the check adds nothing but version skew.)"""
-    sm = shard_map_fn()
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
-def use_mesh(mesh: Mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    jax >= 0.6 spells it ``jax.set_mesh(mesh)``; on 0.4.x entering the
-    ``Mesh`` itself sets the ambient mesh pjit/shard_map resolve against.
-    Use this instead of either spelling directly (the PR 5
-    ``hint``-resolution fix, promoted to the write side)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
 def hardware_mesh(devices=None, *, axis: str = HW_AXIS) -> Mesh:
     """A 1-D mesh over ``devices`` for hardware-config sharding.
 
@@ -168,42 +126,15 @@ def repair_spec(spec, shape, axis_size) -> "P":
 
 
 def _ambient_mesh_auto_axes():
-    """(mesh, auto axis names) of the ambient mesh, across jax versions.
-
-    jax >= 0.5 exposes ``jax.sharding.get_abstract_mesh()`` with per-axis
-    ``AxisType`` (Manual axes inside shard_map must not be pinned); on
-    jax 0.4.x the ambient mesh is the ``with mesh:`` context mesh from
-    ``thread_resources`` and every axis is implicitly auto.  Outside any
-    mesh both paths return (None, ()).
-    """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        am = get_am()
-        if am is None or not am.axis_names:
-            return None, ()
-        from jax.sharding import AxisType
-
-        return am, tuple(
-            n for n, t in zip(am.axis_names, am.axis_types)
-            if t == AxisType.Auto
-        )
-    from jax._src import core as core_lib
-    from jax._src import mesh as mesh_lib
-
-    pm = mesh_lib.thread_resources.env.physical_mesh
-    if pm is None or pm.empty or not pm.axis_names:
+    """(mesh, auto axis names) of the ambient abstract mesh; Manual axes
+    (inside shard_map) must not be pinned.  Outside any mesh: (None, ())."""
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or not am.axis_names:
         return None, ()
-    # Inside shard_map the mesh axes are bound in the trace-time axis env —
-    # those are Manual and must not be pinned (0.4.x has no AxisType, so
-    # this is the only way to see them).
-    manual: set = set()
-    get_env = getattr(core_lib, "get_axis_env", None)
-    if get_env is not None:
-        try:
-            manual = set(get_env().axis_sizes)
-        except Exception:  # pragma: no cover - defensive across 0.4.x micros
-            manual = set()
-    return pm, tuple(n for n in pm.axis_names if n not in manual)
+    return am, tuple(
+        n for n, t in zip(am.axis_names, am.axis_types)
+        if t == jax.sharding.AxisType.Auto
+    )
 
 
 def hint(x, *spec):

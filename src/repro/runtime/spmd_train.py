@@ -23,7 +23,6 @@ from jax.sharding import PartitionSpec as P
 from ..models import model as M
 from ..optim import AdamWConfig, adamw_update, warmup_cosine
 from ..parallel.compression import compressed_psum, ef_apply
-from ..parallel.sharding import shard_map_unchecked
 
 
 def make_compressed_train_step(cfg, rc, mesh, opt_cfg: AdamWConfig | None = None):
@@ -46,9 +45,9 @@ def make_compressed_train_step(cfg, rc, mesh, opt_cfg: AdamWConfig | None = None
     inner_axes = tuple(a for a in mesh.axis_names if a != "pod")
 
     @partial(
-        shard_map_unchecked, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), P(), P("pod")),
-        out_specs=(P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P()), check_vma=False,
     )
     def step(params, opt_state, ef, batch):
         l, g = grad_fn(params, batch)  # per-pod mean gradient

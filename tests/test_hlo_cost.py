@@ -32,8 +32,6 @@ def test_scan_multiplies_by_trip_count():
     w = HC.module_cost(compile_text(g, x, ws))
     assert w.dot_flops == 10 * 2 * 128 ** 3
     ca = jax.jit(g).lower(x, ws).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per device
-        ca = ca[0]
     assert ca["flops"] < w.dot_flops / 5  # cost_analysis is loop-blind
 
 

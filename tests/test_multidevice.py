@@ -71,7 +71,7 @@ def test_sharded_train_step_matches_single_device():
     params_s = jax.device_put(params, pshard)
     opt_s = jax.device_put(opt, oshard)
     batch_s = jax.device_put(batch, bshard)
-    with SH.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         p2, o2, m2 = jax.jit(step, in_shardings=(pshard, oshard, bshard))(
             params_s, opt_s, batch_s)
     print("loss1", float(m1["loss"]), "loss2", float(m2["loss"]))
@@ -129,7 +129,7 @@ def test_pipeline_parallel_matches_sequential():
         return jnp.tanh(x @ w)
 
     x = jax.random.normal(jax.random.key(1), (n_micro, mb, d))
-    with SH.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = pipeline_apply(stage_fn, ws, x, mesh=mesh)
     # sequential reference
     ref = x
@@ -163,7 +163,7 @@ def test_compressed_train_step_learns_with_s8_wire():
     ef = init_ef(params)
     batch = {"tokens": jax.random.randint(jax.random.key(1), (8, 32), 0, 128),
              "labels": jax.random.randint(jax.random.key(2), (8, 32), 0, 128)}
-    with SH.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep = jax.jit(step)
         losses = []
         for _ in range(8):
@@ -251,19 +251,18 @@ def test_compressed_psum_accuracy_and_wire_dtype():
     from functools import partial
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh
-    from repro.parallel import sharding as SH
     from repro.parallel.compression import compressed_psum
 
     mesh = make_mesh((2,), ("pod",))
 
-    @partial(SH.shard_map_unchecked, mesh=mesh, in_specs=P("pod"),
-             out_specs=P("pod"))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+             out_specs=P("pod"), check_vma=False)
     def sync(x):
         out, err = compressed_psum(x[0], "pod", mean=True)
         return (out + err * 0)[None]
 
     x = jax.random.normal(jax.random.key(0), (2, 1024)) * 3.0
-    with SH.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = sync(x)
         txt = jax.jit(sync).lower(x).compile().as_text()
     expect = x.mean(axis=0)

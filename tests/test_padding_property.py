@@ -39,7 +39,7 @@ def test_padding_invariance_property(seed, n, node_pad, edge_pad, row_pad):
 
     feat = g.node_features()
     esrc, edst, ewords = g.edge_arrays()
-    with M.enable_x64():
+    with M.enable_x64(True):
         ref = M.compose_metrics(M._evaluate_batch_graph(
             feat, esrc, edst, ewords, g.source_mask, g.sink_mask, cuts,
             hw_rows, ac,

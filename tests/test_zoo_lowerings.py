@@ -27,7 +27,7 @@ def _lockstep(g):
     ac = M.area_consts_of(HW)
     feat = g.node_features()
     esrc, edst, ewords = g.edge_arrays()
-    with M.enable_x64():
+    with M.enable_x64(True):
         batch = M.compose_metrics(M._evaluate_batch_graph(
             feat, esrc, edst, ewords, g.source_mask, g.sink_mask, cuts,
             hw_rows, ac,

@@ -39,7 +39,7 @@ def _assert_lockstep_and_padding_inert(g, seed: int) -> None:
     ac = M.area_consts_of(HW)
     feat = g.node_features()
     esrc, edst, ewords = g.edge_arrays()
-    with M.enable_x64():
+    with M.enable_x64(True):
         batch = M.compose_metrics(M._evaluate_batch_graph(
             feat, esrc, edst, ewords, g.source_mask, g.sink_mask, cuts,
             hw_rows, ac,
@@ -161,7 +161,7 @@ def test_zero_state_graph_unchanged_by_state_column(seed):
     esrc, edst, ewords = g.edge_arrays()
     zeroed = feat.copy()
     zeroed[:, M.F_STATE] = 0.0
-    with M.enable_x64():
+    with M.enable_x64(True):
         a = M._evaluate_batch_graph(
             feat, esrc, edst, ewords, g.source_mask, g.sink_mask, cuts,
             hw_rows, ac,
