@@ -23,14 +23,15 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
-import time
 from typing import Sequence
 
+import jax
 import numpy as np
 from jax import enable_x64
 
 from . import fusion
 from . import metrics as M
+from . import spans
 from ..parallel.sharding import hardware_mesh, mesh_fingerprint
 from .arch import Constraints, DLAConfig, default_config_space
 from .errors import (
@@ -73,7 +74,9 @@ class FlowResult:
     n_feasible: int
     n_pruned: int  # groupings dropped by the SRAM prefilter before the sweep
     compile_seconds: float  # XLA compile paid by this call (0 on cache hit)
-    sweep_seconds: float  # the single timed execution
+    # The sweep's execution (input transfer, dispatch, kernel) plus the
+    # device-to-host fetch of its raw plane: fleet.execute + fleet.fetch.
+    sweep_seconds: float
     candidates_per_second: float
     # Provenance of the grouping candidates: "exhaustive" / "pool" /
     # "explicit", or — for groupings="search"/"dp" — the engine that
@@ -199,28 +202,36 @@ def _compiled_sweep(
     touching the process-global JAX precision config.  ``mesh_key``
     (:data:`_SINGLE_MESH_KEY` or a sharded mesh fingerprint) is part of
     the cache key: device layout changes the compiled program even at
-    identical argument shapes."""
+    identical argument shapes.  A cache miss is the ``fleet.compile``
+    span, whose duration is the compile time returned."""
     key = (getattr(fn, "__name__", str(fn)), mesh_key) + tuple(
         (a.shape, str(a.dtype)) for a in args
     )
     exe = _sweep_cache_get(key)
     if exe is not None:
         return exe, 0.0
-    t0 = time.perf_counter()
-    with enable_x64(True):
-        exe = fn.lower(*args).compile()
-    dt = time.perf_counter() - t0
+    with spans.span("fleet.compile") as sp:
+        with enable_x64(True):
+            exe = fn.lower(*args).compile()
     _sweep_cache_put(key, exe)
-    return exe, dt
+    return exe, sp.record.seconds
 
 
 def _run_sweep(exe, args) -> tuple[np.ndarray, float]:
-    """(result, sweep_seconds): one timed execution of an AOT executable
-    (inside ``enable_x64`` — the executable's avals are float64)."""
-    t1 = time.perf_counter()
-    with enable_x64(True):
-        out = np.asarray(exe(*args))
-    return out, time.perf_counter() - t1
+    """(raw plane, sweep_seconds): one execution of an AOT executable
+    (inside ``enable_x64`` — the executable's avals are float64).
+
+    Two spans: ``fleet.execute`` runs the program until the device is
+    done with it (input transfer, dispatch, kernel), ``fleet.fetch``
+    copies the plane to the host (its ``work`` is the plane's bytes);
+    sweep_seconds is the sum of their durations."""
+    with spans.span("fleet.execute") as ex:
+        with enable_x64(True):
+            dev = jax.block_until_ready(exe(*args))
+    with spans.span("fleet.fetch") as fe:
+        out = np.asarray(dev)
+        fe.work = out.nbytes
+    return out, ex.record.seconds + fe.record.seconds
 
 
 def _metrics_from_row(row: np.ndarray) -> M.Metrics:
@@ -581,8 +592,10 @@ def run_flow(
 
     The evaluator is AOT-compiled once per argument-shape signature;
     ``compile_seconds`` reports the XLA compilation paid by *this* call
-    (0 on an executable-cache hit) and ``sweep_seconds`` /
-    ``candidates_per_second`` the single timed execution.
+    (0 on an executable-cache hit, the ``fleet.compile`` span) and
+    ``sweep_seconds`` the execution plus the device-to-host fetch of the
+    raw plane (the ``fleet.execute`` and ``fleet.fetch`` spans);
+    ``candidates_per_second`` is candidates over ``sweep_seconds``.
 
     ``pareto=True`` additionally extracts the feasible sweep's
     (bandwidth, latency, energy, area) Pareto front into
@@ -693,7 +706,9 @@ class FleetResult:
     n_graphs: int
     n_candidates: int  # real (graph, hw, cut) triples across the fleet
     compile_seconds: float  # ONE compile amortised across the whole fleet
-    sweep_seconds: float  # the single timed (G, H, C) execution
+    # The (G, H, C) execution plus the device-to-host fetch of its raw
+    # plane (fleet.execute + fleet.fetch, summed over hw chunks).
+    sweep_seconds: float
     candidates_per_second: float
     # Device layout the sweep ran on: 1 for the single-device program,
     # else the size of the 1-D `hardware` mesh the H axis was sharded over.
@@ -733,6 +748,7 @@ class FleetResult:
         return "\n".join(lines)
 
 
+@spans.span("fleet.call")
 def run_fleet(
     irs: Sequence[NetworkIR | GraphIR],
     *,
@@ -820,6 +836,13 @@ def run_fleet(
     killing a kernel mid-flight.  ``hw_chunk`` cannot be combined with
     ``devices`` (the sharded program already splits H across the mesh).
 
+    Each call is one ``fleet.call`` span (:mod:`repro.core.spans`); its
+    stages are child spans that cover it end to end: ``fleet.prepare``,
+    ``fleet.compile`` (executable-cache misses only), ``fleet.execute``
+    and ``fleet.fetch`` (once per hw chunk), ``fleet.compose``,
+    ``fleet.guard`` and ``fleet.select``.  ``compile_seconds`` and
+    ``sweep_seconds`` are the durations of those spans.
+
     Fault tolerance (all off by default):
 
     * ``retry_policy`` (:class:`repro.core.errors.RetryPolicy`) retries
@@ -879,101 +902,102 @@ def run_fleet(
         )
     if config_space is None:
         config_space = default_config_space()
-    graphs = [as_graph(ir) for ir in irs]
+    with spans.span("fleet.prepare"):
+        graphs = [as_graph(ir) for ir in irs]
 
-    # ``groupings`` may be one spec shared by the whole fleet, or a
-    # per-graph sequence of explicit (C_i, E_i) cut batches (the planning
-    # service resolves each request's grouping through its deadline ladder
-    # and sweeps the mixed batch as one fleet program).
-    if isinstance(groupings, (list, tuple)):
-        if len(groupings) != len(graphs):
-            raise ValueError(
-                f"{len(groupings)} grouping specs for {len(graphs)} graphs"
-            )
-        specs = list(groupings)
-    else:
-        specs = [groupings] * len(graphs)
-
-    # Per-graph grouping resolution + SRAM prefilter (padded-E cut rows).
-    edge_bucket = bucket_size(
-        max(g.n_edges for g in graphs), EDGE_BUCKET_FLOOR
-    )
-    node_bucket = bucket_size(
-        max(g.n_nodes for g in graphs), NODE_BUCKET_FLOOR
-    )
-    padded = [pad_graph(g, n_nodes=node_bucket, n_edges=edge_bucket)
-              for g in graphs]
-    cuts: list[np.ndarray] = []
-    pruned: list[int] = []
-    provenances: list[str] = []
-    for g, pg, spec in zip(graphs, padded, specs):
-        cb, provenance = groupings_batch(
-            g, spec, sram_budget_words=sram_budget_words,
-            with_provenance=True,
-        )
-        cb = pad_cuts_batch(cb, edge_bucket)
-        provenances.append(provenance)
-        n_pruned = 0
-        if np.isfinite(sram_budget_words):
-            max_int = fusion.padded_max_intermediate_batch(pg, cb)
-            keep = max_int <= sram_budget_words
-            n_pruned = int(cb.shape[0] - keep.sum())
-            if not keep.any():
-                raise InfeasibleBudgetError(
-                    f"{g.name}: no grouping fits the SRAM budget "
-                    f"({sram_budget_words:.0f} words; the cheapest offered "
-                    f"grouping needs {max_int.min():.0f})",
-                    min_feasible_budget_words=float(max_int.min()),
+        # ``groupings`` may be one spec shared by the whole fleet, or a
+        # per-graph sequence of explicit (C_i, E_i) cut batches (the planning
+        # service resolves each request's grouping through its deadline ladder
+        # and sweeps the mixed batch as one fleet program).
+        if isinstance(groupings, (list, tuple)):
+            if len(groupings) != len(graphs):
+                raise ValueError(
+                    f"{len(groupings)} grouping specs for {len(graphs)} graphs"
                 )
-            cb = cb[keep]
-        cuts.append(cb)
-        pruned.append(n_pruned)
-    counts = [cb.shape[0] for cb in cuts]
-    cut_bucket = bucket_size(max(counts), CUT_BUCKET_FLOOR)
-    cuts = [pad_cuts_batch(cb, edge_bucket, cut_bucket) for cb in cuts]
+            specs = list(groupings)
+        else:
+            specs = [groupings] * len(graphs)
 
-    hw_rows = np.stack([c.as_row() for c in config_space])
-    area_consts = M.area_consts_of_space(config_space)
-    H = hw_rows.shape[0]
-
-    # Device layout: single-device vmapped program, or the same kernel
-    # shard_mapped over a 1-D `hardware` mesh with H padded to a
-    # device-count multiple (padded rows are copies of config 0 — fully
-    # valid arithmetic, sliced off below before metrics composition).
-    mesh_key = _SINGLE_MESH_KEY
-    hw_swept = hw_rows
-    if devices is None:
-        kernel = M._jit_fleet_graph
-    else:
-        mesh = hardware_mesh(devices)
-        kernel = M.sharded_fleet_kernel(mesh)
-        mesh_key = mesh_fingerprint(mesh)
-        D = int(mesh.devices.size)
-        H_padded = -(-H // D) * D
-        if H_padded > H:
-            hw_swept = np.concatenate(
-                [hw_rows, np.repeat(hw_rows[:1], H_padded - H, axis=0)]
+        # Per-graph grouping resolution + SRAM prefilter (padded-E cut rows).
+        edge_bucket = bucket_size(
+            max(g.n_edges for g in graphs), EDGE_BUCKET_FLOOR
+        )
+        node_bucket = bucket_size(
+            max(g.n_nodes for g in graphs), NODE_BUCKET_FLOOR
+        )
+        padded = [pad_graph(g, n_nodes=node_bucket, n_edges=edge_bucket)
+                  for g in graphs]
+        cuts: list[np.ndarray] = []
+        pruned: list[int] = []
+        provenances: list[str] = []
+        for g, pg, spec in zip(graphs, padded, specs):
+            cb, provenance = groupings_batch(
+                g, spec, sram_budget_words=sram_budget_words,
+                with_provenance=True,
             )
+            cb = pad_cuts_batch(cb, edge_bucket)
+            provenances.append(provenance)
+            n_pruned = 0
+            if np.isfinite(sram_budget_words):
+                max_int = fusion.padded_max_intermediate_batch(pg, cb)
+                keep = max_int <= sram_budget_words
+                n_pruned = int(cb.shape[0] - keep.sum())
+                if not keep.any():
+                    raise InfeasibleBudgetError(
+                        f"{g.name}: no grouping fits the SRAM budget "
+                        f"({sram_budget_words:.0f} words; the cheapest "
+                        f"offered grouping needs {max_int.min():.0f})",
+                        min_feasible_budget_words=float(max_int.min()),
+                    )
+                cb = cb[keep]
+            cuts.append(cb)
+            pruned.append(n_pruned)
+        counts = [cb.shape[0] for cb in cuts]
+        cut_bucket = bucket_size(max(counts), CUT_BUCKET_FLOOR)
+        cuts = [pad_cuts_batch(cb, edge_bucket, cut_bucket) for cb in cuts]
 
-    args = (
-        np.stack([pg.feat for pg in padded]),
-        np.stack([pg.esrc for pg in padded]),
-        np.stack([pg.edst for pg in padded]),
-        np.stack([pg.ewords for pg in padded]),
-        np.stack([pg.src_mask for pg in padded]),
-        np.stack([pg.sink_mask for pg in padded]),
-        np.stack(cuts),
-        hw_swept,
-        area_consts,
-        np.stack([pg.node_mask for pg in padded]),
-        np.stack([pg.edge_mask for pg in padded]),
-    )
-    # f64-exactness guard on the giant-config feature tables (llama4 /
-    # arctic edge words reach ~1e10 — far below 2^53, but a corrupted or
-    # overflowed table must fail loudly before the sweep, not split ulps
-    # silently inside it).
-    M.assert_exact_f64(args[0], what="fleet feature table")
-    M.assert_exact_f64(args[3], what="fleet edge words")
+        hw_rows = np.stack([c.as_row() for c in config_space])
+        area_consts = M.area_consts_of_space(config_space)
+        H = hw_rows.shape[0]
+
+        # Device layout: single-device vmapped program, or the same kernel
+        # shard_mapped over a 1-D `hardware` mesh with H padded to a
+        # device-count multiple (padded rows are copies of config 0 — fully
+        # valid arithmetic, sliced off below before metrics composition).
+        mesh_key = _SINGLE_MESH_KEY
+        hw_swept = hw_rows
+        if devices is None:
+            kernel = M._jit_fleet_graph
+        else:
+            mesh = hardware_mesh(devices)
+            kernel = M.sharded_fleet_kernel(mesh)
+            mesh_key = mesh_fingerprint(mesh)
+            D = int(mesh.devices.size)
+            H_padded = -(-H // D) * D
+            if H_padded > H:
+                hw_swept = np.concatenate(
+                    [hw_rows, np.repeat(hw_rows[:1], H_padded - H, axis=0)]
+                )
+
+        args = (
+            np.stack([pg.feat for pg in padded]),
+            np.stack([pg.esrc for pg in padded]),
+            np.stack([pg.edst for pg in padded]),
+            np.stack([pg.ewords for pg in padded]),
+            np.stack([pg.src_mask for pg in padded]),
+            np.stack([pg.sink_mask for pg in padded]),
+            np.stack(cuts),
+            hw_swept,
+            area_consts,
+            np.stack([pg.node_mask for pg in padded]),
+            np.stack([pg.edge_mask for pg in padded]),
+        )
+        # f64-exactness guard on the giant-config feature tables (llama4 /
+        # arctic edge words reach ~1e10 — far below 2^53, but a corrupted or
+        # overflowed table must fail loudly before the sweep, not split ulps
+        # silently inside it).
+        M.assert_exact_f64(args[0], what="fleet feature table")
+        M.assert_exact_f64(args[3], what="fleet edge words")
     if abort_check is not None:
         abort_check()
 
@@ -1049,6 +1073,7 @@ def run_fleet(
             ckpt = SweepCheckpoint(checkpoint_dir)
             restored = ckpt.load(sweep_fingerprint(args, hw_chunk))
         detector = StragglerDetector(min_deadline_s=0.0)
+        call = spans.current()  # this call's fleet.call span
         compile_seconds = sweep_seconds = 0.0
         chunks_computed = 0
         stragglers: list[int] = []
@@ -1064,14 +1089,16 @@ def run_fleet(
             chunk_args = (
                 args[:7] + (hw_rows[h0:h0 + hw_chunk],) + args[8:]
             )
-            t_chunk = time.perf_counter()
+            seen = len(call.children)
             plane, dt_c, dt_s = _compute(
                 ci, chunk_args, kernel, mesh_key, h0, sweep_device_count
             )
-            # Straggler detection on wall time net of compile (a cold
-            # cache is not a sick worker); the detector needs 5 samples
-            # before it flags, so early chunks only seed the median.
-            dt_wall = time.perf_counter() - t_chunk - dt_c
+            # Straggler detection on the chunk's execute + fetch spans,
+            # retried attempts included, compile left out (a cold cache
+            # is not a sick worker); the detector needs 5 samples before
+            # it flags, so early chunks only seed the median.
+            dt_wall = sum(r.seconds for r in call.children[seen:]
+                          if r.name != "fleet.compile")
             if detector.is_straggler(dt_wall):
                 stragglers.append(ci)
             detector.observe(dt_wall)
@@ -1083,42 +1110,48 @@ def run_fleet(
             sweep_seconds += dt_s
         straggler_chunks = tuple(stragglers)
         raw = np.concatenate(planes, axis=1)
-    out = M.compose_metrics(raw[:, :H], hw_rows)  # (G, H, C_b, 4)
+    with spans.span("fleet.compose"):
+        out = M.compose_metrics(raw[:, :H], hw_rows)  # (G, H, C_b, 4)
     # Finite guard over the whole fleet's raw plane: poisoned cells are
     # quarantined per graph before any argmin/Pareto selection.
-    poison_all = M.poison_mask(raw[:, :H])  # (G, H, C_b)
-    any_poison = bool(poison_all.any())
-    fleet_cells: list[QuarantinedCell] = []
+    with spans.span("fleet.guard"):
+        poison_all = M.poison_mask(raw[:, :H])  # (G, H, C_b)
+        any_poison = bool(poison_all.any())
+        fleet_cells: list[QuarantinedCell] = []
+        g_poisons: list[np.ndarray | None] = [None] * len(graphs)
+        g_quars: list[QuarantineReport | None] = [None] * len(graphs)
+        if any_poison:
+            for gi in range(len(graphs)):
+                pm = poison_all[gi, :, :counts[gi]]
+                if pm.any():
+                    cells = _quarantine_cells(
+                        raw[gi, :H, :counts[gi]], pm, graph=gi
+                    )
+                    g_quars[gi] = QuarantineReport(cells=cells)
+                    fleet_cells.extend(cells)
+                    g_poisons[gi] = pm
     n_cand = H * sum(counts)
     fleet_cps = n_cand / max(sweep_seconds, 1e-9)
-    results = []
-    for gi, g in enumerate(graphs):
-        C = counts[gi]
-        g_poison = None
-        g_quar = None
-        if any_poison:
-            pm = poison_all[gi, :, :C]
-            if pm.any():
-                cells = _quarantine_cells(raw[gi, :H, :C], pm, graph=gi)
-                g_quar = QuarantineReport(cells=cells)
-                fleet_cells.extend(cells)
-                g_poison = pm
-        results.append(
-            _best_flow_result(
-                out[gi, :, :C],  # padded candidate rows sliced off
-                cuts[gi][:C, : g.n_edges],
-                g, config_space, constraints,
-                n_pruned=pruned[gi],
-                compile_seconds=0.0,  # the one fleet compile, see FleetResult
-                sweep_seconds=sweep_seconds,
-                candidates_per_second=fleet_cps,  # the shared execution rate
-                search_engine=provenances[gi],
-                err_prefix=f"{g.name}: ",
-                pareto=pareto,
-                poison=g_poison,
-                quarantine=g_quar,
+    with spans.span("fleet.select"):
+        results = []
+        for gi, g in enumerate(graphs):
+            C = counts[gi]
+            results.append(
+                _best_flow_result(
+                    out[gi, :, :C],  # padded candidate rows sliced off
+                    cuts[gi][:C, : g.n_edges],
+                    g, config_space, constraints,
+                    n_pruned=pruned[gi],
+                    compile_seconds=0.0,  # the one fleet compile, see above
+                    sweep_seconds=sweep_seconds,
+                    candidates_per_second=fleet_cps,  # the shared rate
+                    search_engine=provenances[gi],
+                    err_prefix=f"{g.name}: ",
+                    pareto=pareto,
+                    poison=g_poisons[gi],
+                    quarantine=g_quars[gi],
+                )
             )
-        )
     return FleetResult(
         results=tuple(results),
         n_graphs=len(graphs),
