@@ -75,7 +75,8 @@ class FlowResult:
     n_pruned: int  # groupings dropped by the SRAM prefilter before the sweep
     compile_seconds: float  # XLA compile paid by this call (0 on cache hit)
     # The sweep's execution (input transfer, dispatch, kernel) plus the
-    # device-to-host fetch of its raw plane: fleet.execute + fleet.fetch.
+    # device-to-host fetch of its result, the raw plane or the pruned
+    # program's summary: fleet.execute + fleet.fetch.
     sweep_seconds: float
     candidates_per_second: float
     # Provenance of the grouping candidates: "exhaustive" / "pool" /
@@ -205,7 +206,7 @@ def _compiled_sweep(
     identical argument shapes.  A cache miss is the ``fleet.compile``
     span, whose duration is the compile time returned."""
     key = (getattr(fn, "__name__", str(fn)), mesh_key) + tuple(
-        (a.shape, str(a.dtype)) for a in args
+        (a.shape, a.dtype.str) for a in args  # str(dtype) costs ~7 us
     )
     exe = _sweep_cache_get(key)
     if exe is not None:
@@ -217,20 +218,69 @@ def _compiled_sweep(
     return exe, sp.record.seconds
 
 
-def _run_sweep(exe, args) -> tuple[np.ndarray, float]:
+class DevicePlane:
+    """Read-only view of a sweep's raw (G, H, C, 5) float64 plane while it
+    is still on the device, with the pruned program's
+    :class:`~repro.core.metrics.PlaneSummary` already on the host.
+
+    ``plane[idx]`` gathers the indexed cells on the device and returns a
+    numpy array; ``np.asarray(plane)`` copies the whole plane.  Both work
+    outside ``enable_x64``.  :func:`run_fleet` releases the device array
+    before it returns, so a view kept past its call reads nothing."""
+
+    def __init__(self, array: jax.Array, summary: M.PlaneSummary):
+        """View ``array``, the plane the program returned with ``summary``,
+        and note whether the summary alone decides every graph's pick."""
+        self._array = array
+        self.summary = summary
+        self.decides = summary.decides()
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        """The plane's (G, H, C, 5) shape."""
+        return self._array.shape
+
+    @property
+    def dtype(self) -> np.dtype:
+        """The plane's dtype, float64."""
+        return np.dtype(self._array.dtype)
+
+    def __getitem__(self, idx) -> np.ndarray:
+        with enable_x64(True):
+            return np.asarray(self._array[idx])
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        out = np.asarray(self._array)
+        return out if dtype is None else out.astype(dtype)
+
+    def release(self) -> None:
+        """Drop the reference to the device array."""
+        self._array = None
+
+
+def _run_sweep(exe, args) -> tuple[np.ndarray | DevicePlane, float]:
     """(raw plane, sweep_seconds): one execution of an AOT executable
     (inside ``enable_x64`` — the executable's avals are float64).
 
     Two spans: ``fleet.execute`` runs the program until the device is
     done with it (input transfer, dispatch, kernel), ``fleet.fetch``
-    copies the plane to the host (its ``work`` is the plane's bytes);
-    sweep_seconds is the sum of their durations."""
+    copies its result to the host, and its ``work`` is the bytes copied;
+    sweep_seconds is the sum of their durations.  A plain sweep's plane is
+    copied whole.  The pruned program (:func:`~repro.core.metrics.
+    _evaluate_fleet_graph_pruned`) returns the plane and a summary: only
+    the summary is copied, and the plane comes back as a
+    :class:`DevicePlane`."""
     with spans.span("fleet.execute") as ex:
         with enable_x64(True):
             dev = jax.block_until_ready(exe(*args))
     with spans.span("fleet.fetch") as fe:
-        out = np.asarray(dev)
-        fe.work = out.nbytes
+        if isinstance(dev, tuple):
+            plane, summary = dev
+            out = DevicePlane(plane, jax.device_get(summary))
+            fe.work = sum(a.nbytes for a in out.summary)
+        else:
+            out = np.asarray(dev)
+            fe.work = out.nbytes
     return out, ex.record.seconds + fe.record.seconds
 
 
@@ -468,20 +518,9 @@ def _best_flow_result(
         )
     energy = np.where(feasible, out[:, :, 2], np.inf)
     ties = np.argwhere(energy == energy.min())  # (h, c) lexicographic order
-    if len(ties) > 1:
-        rows = out[ties[:, 0], ties[:, 1]]  # (k, 4)
-        order = np.lexsort(
-            (ties[:, 1], ties[:, 0], rows[:, 3], rows[:, 1], rows[:, 0])
-        )
-        ties = ties[order[:1]]
-    h, c = ties[0]
-    labels = fusion.cut_group_labels(g, cuts_batch[c])
-    sizes = tuple(len(grp) for grp in fusion.groups_from_labels(labels))
-    return FlowResult(
-        best_hw=config_space[h],
-        best_cuts=cuts_batch[c],
-        best_metrics=_metrics_from_row(out[h, c]),
-        group_sizes=sizes,
+    h, c = ties[_pick(out[ties[:, 0], ties[:, 1]], ties[:, 0], ties[:, 1])]
+    return _flow_result(
+        g, cuts_batch, config_space, h, c, out[h, c],
         n_candidates=out.shape[0] * out.shape[1],
         n_feasible=n_feas,
         n_pruned=n_pruned,
@@ -496,6 +535,80 @@ def _best_flow_result(
             else None
         ),
         quarantine=quarantine,
+    )
+
+
+def _pick(rows: np.ndarray, hs: np.ndarray, cs: np.ndarray) -> int:
+    """Index of the winner among feasible (N, 4) metric rows at hardware
+    indices ``hs`` and cut indices ``cs``: the least energy, ties broken
+    by the lexicographic minimum of (bandwidth, latency, area, h, c)."""
+    ties = np.flatnonzero(rows[:, 2] == rows[:, 2].min())
+    if len(ties) > 1:
+        t = rows[ties]
+        ties = ties[
+            np.lexsort((cs[ties], hs[ties], t[:, 3], t[:, 1], t[:, 0]))
+        ]
+    return int(ties[0])
+
+
+def _flow_result(
+    g: GraphIR,
+    cuts_batch: np.ndarray,
+    config_space: Sequence[DLAConfig],
+    h: int,
+    c: int,
+    row: np.ndarray,  # (4,) the winner's metrics
+    **fields,
+) -> FlowResult:
+    """The FlowResult of the winner (h, c): its point, cuts and groups."""
+    labels = fusion.cut_group_labels(g, cuts_batch[c])
+    sizes = tuple(len(grp) for grp in fusion.groups_from_labels(labels))
+    return FlowResult(
+        best_hw=config_space[h],
+        best_cuts=cuts_batch[c],
+        best_metrics=_metrics_from_row(row),
+        group_sizes=sizes,
+        **fields,
+    )
+
+
+def _compose_survivors(
+    summary: M.PlaneSummary, hw_rows: np.ndarray
+) -> list[tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]]:
+    """Each graph's survivor rows from the pruned program's summary, as
+    (metrics (n, 4), h (n,), c (n,), surely feasible (n,)).  Composing is
+    elementwise, so each row's energy is bit-identical to its cell of the
+    composed plane."""
+    out = []
+    for gi, n in enumerate(summary.n_survivors):
+        hs, cs = summary.h[gi, :n], summary.c[gi, :n]
+        rows = M.compose_metrics(summary.rows[gi, :n, None, :],
+                                 hw_rows[hs])[:, 0]
+        out.append((rows, hs, cs, summary.sure[gi, :n]))
+    return out
+
+
+def _survivor_flow_result(
+    survivors: tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray],
+    n_sure: int,
+    cuts_batch: np.ndarray,
+    g: GraphIR,
+    config_space: Sequence[DLAConfig],
+    constraints: Constraints,
+    **fields,
+) -> FlowResult:
+    """:func:`_best_flow_result` over one graph's survivor rows: the
+    undecided rows are decided here exactly, and every row the device
+    classed surely feasible is feasible here too, so ``n_feasible`` is
+    ``n_sure`` plus the undecided rows within the limits."""
+    rows, hs, cs, sure = survivors
+    feasible = np.all(rows <= constraints.as_row()[None, :], axis=-1)
+    idx = np.flatnonzero(feasible)
+    i = idx[_pick(rows[idx], hs[idx], cs[idx])]
+    return _flow_result(
+        g, cuts_batch, config_space, hs[i], cs[i], rows[i],
+        n_feasible=int(n_sure) + int(np.sum(feasible & ~sure)),
+        **fields,
     )
 
 
@@ -706,8 +819,8 @@ class FleetResult:
     n_graphs: int
     n_candidates: int  # real (graph, hw, cut) triples across the fleet
     compile_seconds: float  # ONE compile amortised across the whole fleet
-    # The (G, H, C) execution plus the device-to-host fetch of its raw
-    # plane (fleet.execute + fleet.fetch, summed over hw chunks).
+    # The (G, H, C) execution plus the device-to-host fetch of its result
+    # (fleet.execute + fleet.fetch, summed over hw chunks).
     sweep_seconds: float
     candidates_per_second: float
     # Device layout the sweep ran on: 1 for the single-device program,
@@ -795,12 +908,23 @@ def run_fleet(
     multiple with copies of config 0 — inert rows sliced off before
     metrics composition, the PR 4 padding idiom on the hardware axis — and
     each device evaluates its H-shard locally; the (G, H, C, 5) raw plane
-    comes back in one cross-device gather and the per-graph
-    argmin/Pareto run on the host exactly as in the single-device path, so
-    sharded results are **bit-identical** at any device count (asserted at
-    1/2/8 host devices in tests/test_multidevice.py).  The executable
-    cache keys on the mesh fingerprint, so per-layout programs never
-    collide (``sweep_cache_stats()["entries"]``).
+    comes back in one cross-device gather and the per-graph argmin/Pareto
+    run on the host over the whole plane, so sharded results are
+    **bit-identical** at any device count (asserted at 1/2/8 host devices
+    in tests/test_multidevice.py).  The executable cache keys on the mesh
+    fingerprint, so per-layout programs never collide
+    (``sweep_cache_stats()["entries"]``).
+
+    Where only each graph's pick is asked for — ``pareto=False``, no
+    ``hw_chunk`` and ``devices=None`` — the sweep program also prunes the
+    plane on the device (:func:`repro.core.metrics.
+    _evaluate_fleet_graph_pruned`) and only its summary is fetched: the
+    counts, and at most :data:`~repro.core.metrics.PRUNE_ROWS` raw rows a
+    graph that can still win.  The host composes their energy in float64
+    and picks with the same rule as the whole-plane path, so the result
+    is bit-identical to it.  A plane with a cell the finite guard could
+    flag, more survivors than the summary holds, or a graph with no
+    surely-feasible cell is fetched whole and takes the whole-plane path.
 
     ``pareto=True`` extracts each workload's feasible-sweep Pareto front
     over (bandwidth, latency, energy, area) into ``results[i].pareto`` —
@@ -827,8 +951,8 @@ def run_fleet(
     axis: the fleet program runs once per ≤``hw_chunk``-row slice of the
     config space and the raw (G, h, C, 5) planes are reassembled before
     metrics composition.  Every raw row is an exact per-candidate f64
-    quantity (energy is composed *outside* XLA), so the chunked sweep is
-    **bit-identical** to the unchunked one — chunking only creates
+    quantity (energy is composed on the host, in numpy), so the chunked
+    sweep is **bit-identical** to the unchunked one — chunking only creates
     preemption points.  ``abort_check`` (a zero-arg callable) is invoked
     before each chunk; raising from it abandons the remaining chunks,
     which is how the planning service implements cooperative cancellation
@@ -841,7 +965,11 @@ def run_fleet(
     ``fleet.compile`` (executable-cache misses only), ``fleet.execute``
     and ``fleet.fetch`` (once per hw chunk), ``fleet.compose``,
     ``fleet.guard`` and ``fleet.select``.  ``compile_seconds`` and
-    ``sweep_seconds`` are the durations of those spans.
+    ``sweep_seconds`` are the durations of those spans.  ``fleet.fetch``'s
+    ``work`` is the bytes it copied (a second fetch of the whole plane
+    where the pruned path falls back), and ``fleet.select``'s the
+    candidate rows the host selection read: the survivors, or H x C a
+    graph on the whole-plane path.
 
     Fault tolerance (all off by default):
 
@@ -871,7 +999,10 @@ def run_fleet(
       passes the finite guard, so injected NaN/Inf/negative/overflow
       cells are quarantined with (g, h, c) provenance
       (``FleetResult.quarantine``) and can never win the argmin or enter
-      a Pareto front.
+      a Pareto front.  On the pruned path the hook is handed the plane
+      still on the device, as a read-only :class:`DevicePlane`; returning
+      that same object keeps the pruned path, and returning anything else
+      runs the whole-plane path on what was returned.
 
     Example — per-graph explicit cut batches (the service/bench form) and
     a sharded hardware axis::
@@ -966,8 +1097,13 @@ def run_fleet(
         # valid arithmetic, sliced off below before metrics composition).
         mesh_key = _SINGLE_MESH_KEY
         hw_swept = hw_rows
+        # Prune on the device where only the pick is asked for, from one
+        # single-device program: the Pareto front needs every feasible row,
+        # and chunked and sharded sweeps keep their raw planes.
+        prune = (not pareto and hw_chunk is None and devices is None
+                 and M.prune_applies(hw_rows, area_consts))
         if devices is None:
-            kernel = M._jit_fleet_graph
+            kernel = M._jit_fleet_graph_pruned if prune else M._jit_fleet_graph
         else:
             mesh = hardware_mesh(devices)
             kernel = M.sharded_fleet_kernel(mesh)
@@ -992,6 +1128,9 @@ def run_fleet(
             np.stack([pg.node_mask for pg in padded]),
             np.stack([pg.edge_mask for pg in padded]),
         )
+        if prune:
+            args += (np.asarray(counts, np.int32),
+                     *M.prune_limits(constraints.as_row()))
         # f64-exactness guard on the giant-config feature tables (llama4 /
         # arctic edge words reach ~1e10 — far below 2^53, but a corrupted or
         # overflowed table must fail loudly before the sweep, not split ulps
@@ -1027,7 +1166,10 @@ def run_fleet(
                 attempt, describe=f"hw chunk {chunk_index}"
             )
         if hook_poison is not None:
-            plane = hook_poison(plane, h0)
+            tapped = hook_poison(plane, h0)
+            if tapped is not plane and isinstance(plane, DevicePlane):
+                plane.release()  # the hook's own plane replaces it
+            plane = tapped
         return plane, dt_c, dt_s
 
     mesh_degraded = False
@@ -1110,48 +1252,79 @@ def run_fleet(
             sweep_seconds += dt_s
         straggler_chunks = tuple(stragglers)
         raw = np.concatenate(planes, axis=1)
-    with spans.span("fleet.compose"):
-        out = M.compose_metrics(raw[:, :H], hw_rows)  # (G, H, C_b, 4)
-    # Finite guard over the whole fleet's raw plane: poisoned cells are
-    # quarantined per graph before any argmin/Pareto selection.
-    with spans.span("fleet.guard"):
-        poison_all = M.poison_mask(raw[:, :H])  # (G, H, C_b)
-        any_poison = bool(poison_all.any())
-        fleet_cells: list[QuarantinedCell] = []
-        g_poisons: list[np.ndarray | None] = [None] * len(graphs)
-        g_quars: list[QuarantineReport | None] = [None] * len(graphs)
-        if any_poison:
-            for gi in range(len(graphs)):
-                pm = poison_all[gi, :, :counts[gi]]
-                if pm.any():
-                    cells = _quarantine_cells(
-                        raw[gi, :H, :counts[gi]], pm, graph=gi
-                    )
-                    g_quars[gi] = QuarantineReport(cells=cells)
-                    fleet_cells.extend(cells)
-                    g_poisons[gi] = pm
     n_cand = H * sum(counts)
+    survivors = None
+    if isinstance(raw, DevicePlane):
+        view = raw
+        try:
+            if view.decides:
+                summary = view.summary
+                with spans.span("fleet.compose"):
+                    survivors = _compose_survivors(summary, hw_rows)
+            else:
+                # Quarantine needs every poisoned cell's provenance, and an
+                # undecided pick needs every row: take the whole plane.
+                with spans.span("fleet.fetch") as fe:
+                    raw = np.asarray(view)
+                    fe.work = raw.nbytes
+                sweep_seconds += fe.record.seconds
+        finally:
+            view.release()
+    fleet_cells: list[QuarantinedCell] = []
+    g_poisons: list[np.ndarray | None] = [None] * len(graphs)
+    g_quars: list[QuarantineReport | None] = [None] * len(graphs)
+    if survivors is not None:
+        with spans.span("fleet.guard"):
+            # The device counted every cell the guard could flag; a plane
+            # with any took the whole-plane path.
+            assert not summary.n_poison.any()
+    else:
+        with spans.span("fleet.compose"):
+            out = M.compose_metrics(raw[:, :H], hw_rows)  # (G, H, C_b, 4)
+        # Finite guard over the whole fleet's raw plane: poisoned cells are
+        # quarantined per graph before any argmin/Pareto selection.
+        with spans.span("fleet.guard"):
+            poison_all = M.poison_mask(raw[:, :H])  # (G, H, C_b)
+            if poison_all.any():
+                for gi in range(len(graphs)):
+                    pm = poison_all[gi, :, :counts[gi]]
+                    if pm.any():
+                        cells = _quarantine_cells(
+                            raw[gi, :H, :counts[gi]], pm, graph=gi
+                        )
+                        g_quars[gi] = QuarantineReport(cells=cells)
+                        fleet_cells.extend(cells)
+                        g_poisons[gi] = pm
     fleet_cps = n_cand / max(sweep_seconds, 1e-9)
-    with spans.span("fleet.select"):
+    with spans.span("fleet.select") as sel:
+        sel.work = (n_cand if survivors is None
+                    else int(summary.n_survivors.sum()))
         results = []
         for gi, g in enumerate(graphs):
             C = counts[gi]
-            results.append(
-                _best_flow_result(
+            fields = dict(
+                n_pruned=pruned[gi],
+                compile_seconds=0.0,  # the one fleet compile, see above
+                sweep_seconds=sweep_seconds,
+                candidates_per_second=fleet_cps,  # the shared rate
+                search_engine=provenances[gi],
+            )
+            batch = cuts[gi][:C, : g.n_edges]
+            if survivors is not None:
+                results.append(_survivor_flow_result(
+                    survivors[gi], summary.n_sure[gi], batch, g,
+                    config_space, constraints, n_candidates=H * C,
+                    **fields))
+            else:
+                results.append(_best_flow_result(
                     out[gi, :, :C],  # padded candidate rows sliced off
-                    cuts[gi][:C, : g.n_edges],
-                    g, config_space, constraints,
-                    n_pruned=pruned[gi],
-                    compile_seconds=0.0,  # the one fleet compile, see above
-                    sweep_seconds=sweep_seconds,
-                    candidates_per_second=fleet_cps,  # the shared rate
-                    search_engine=provenances[gi],
+                    batch, g, config_space, constraints,
                     err_prefix=f"{g.name}: ",
                     pareto=pareto,
                     poison=g_poisons[gi],
                     quarantine=g_quars[gi],
-                )
-            )
+                    **fields,
+                ))
     return FleetResult(
         results=tuple(results),
         n_graphs=len(graphs),

@@ -39,6 +39,7 @@ block's cut space.
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -707,6 +708,183 @@ def _evaluate_fleet_graph(
 
 
 _jit_fleet_graph = jax.jit(_evaluate_fleet_graph)
+
+
+# ---------------------------------------------------------------------------
+# Pruning the fleet plane on the device
+# ---------------------------------------------------------------------------
+
+# Survivor rows each graph's summary carries back to the host.
+PRUNE_ROWS = 256
+# Relative error bound of the float32 pruning key and of each float32
+# column.  A column is one conversion from float64 (error <= u, u = 2^-24).
+# A key term e * x is two conversions and a multiply, and the sum of three
+# nonnegative terms two adds, so |key - E| <= g5 * E with g5 = 5u / (1 - 5u),
+# about 2^-21.7, where E is the exact Eq. (3) energy; the host's float64
+# composition of E adds at most another g5 with u = 2^-53.  Both hold while
+# every nonzero value stays in float32's normal range, which
+# :func:`prune_applies` makes sure of.  2^-20 covers them with room.
+PRUNE_DELTA = 2.0 ** -20
+# The key of a survivor is at most the smallest surely-feasible key times
+# this: (1 + d) / (1 - d), widened by 2^-22 so that rounding the product in
+# float32 can never cut it below that ratio.
+PRUNE_RATIO = np.float32(
+    (1 + PRUNE_DELTA) / (1 - PRUNE_DELTA) * (1 + 2.0 ** -22))
+# Cells above this count as possibly poisoned on the device: a little under
+# MAX_EXACT_WORDS, so that no cell the host's guard flags can slip through,
+# whatever the emulated float64 comparison does at the edge.
+_POISON_FLOOR = float(2 ** 53) * (1 - PRUNE_DELTA)
+
+
+class PlaneSummary(NamedTuple):
+    """What the pruned fleet program returns besides the raw plane, one
+    entry per graph (see :func:`_evaluate_fleet_graph_pruned`)."""
+
+    n_poison: np.ndarray  # (G,) cells the finite guard may flag
+    n_sure: np.ndarray  # (G,) surely-feasible cells
+    n_undecided: np.ndarray  # (G,) cells the float32 classes cannot decide
+    n_survivors: np.ndarray  # (G,) cells that can still win
+    h: np.ndarray  # (G, K) hardware index of each survivor row
+    c: np.ndarray  # (G, K) cut index of each survivor row
+    sure: np.ndarray  # (G, K) whether the survivor is surely feasible
+    rows: np.ndarray  # (G, K, 5) the survivors' raw float64 rows
+
+    def decides(self) -> bool:
+        """Whether the host can finish every graph's pick from the rows:
+        no cell may be poisoned, every graph has a surely-feasible cell,
+        and every survivor came back."""
+        return bool(np.all(self.n_poison == 0) and np.all(self.n_sure > 0)
+                    and np.all(self.n_survivors <= PRUNE_ROWS))
+
+
+def prune_applies(hw_rows: np.ndarray, area_consts: np.ndarray) -> bool:
+    """Whether :data:`PRUNE_DELTA` bounds the float32 pruning key for this
+    design space: every hardware field and area constant is 0 or in
+    [2^-60, 2^60].  Raw rows are then 0 or far inside float32's normal
+    range (word and cycle counts are integers, latency divides them by the
+    bus width, area sums nonnegative multiples of the constants)."""
+    v = np.concatenate([np.ravel(hw_rows), np.ravel(area_consts)])
+    return bool(np.all((v == 0) | ((v >= 2.0 ** -60) & (v <= 2.0 ** 60))))
+
+
+def prune_limits(limits: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """(lo, hi) float32 rows of the four limits widened by ``PRUNE_DELTA``:
+    a column whose float32 value is at most ``lo`` is surely within its
+    limit, one above ``hi`` surely over it.  ``lo`` is rounded down and
+    ``hi`` up; an infinite limit stays as it is."""
+    limits = np.asarray(limits, np.float64)
+    slack = np.where(np.isinf(limits), 0.0, PRUNE_DELTA * np.abs(limits))
+    lo, hi = limits - slack, limits + slack
+    with np.errstate(over="ignore"):  # beyond float32: inf, made finite
+        lo32, hi32 = lo.astype(np.float32), hi.astype(np.float32)
+    lo32 = np.where(lo32 > lo, np.nextafter(lo32, np.float32(-np.inf)), lo32)
+    hi32 = np.where(hi32 < hi, np.nextafter(hi32, np.float32(np.inf)), hi32)
+    return lo32, hi32
+
+
+def _prune_classes(raw, e3, lim_lo, lim_hi):
+    """(key, sure, over) of raw rows (..., 5) whose (e_dram, e_sram, e_pb)
+    are ``e3`` (..., 3): the float32 Eq. (3) key, whether every column is
+    surely within its limit, and whether some column is surely over it."""
+    x = raw.astype(jnp.float32)
+    e = e3.astype(jnp.float32)
+    key = e[..., 0] * x[..., 0] + e[..., 1] * x[..., 2] + e[..., 2] * x[..., 3]
+    cols = (x[..., 0], x[..., 1], key, x[..., 4])
+    sure = jnp.ones(key.shape, bool)
+    over = jnp.zeros(key.shape, bool)
+    for j, v in enumerate(cols):
+        sure &= v <= lim_lo[j]
+        over |= v > lim_hi[j]
+    return key, sure, over
+
+
+def _first_k(mask: jnp.ndarray, k: int) -> jnp.ndarray:
+    """Row-major positions of the first ``k`` True entries of a 2-D mask,
+    ascending; ``mask.size`` where it has fewer.  The running count is
+    taken along rows and then across them: the TPU compiler takes over
+    half a minute for one cumulative sum a million entries long, and under
+    a second for these."""
+    inner = jnp.cumsum(mask.astype(jnp.int32), axis=1)
+    before = jnp.cumsum(inner[:, -1]) - inner[:, -1]
+    pos = (inner + before[:, None]).reshape(-1)
+    return jnp.searchsorted(pos, jnp.arange(1, k + 1, dtype=jnp.int32),
+                            side="left")
+
+
+def _prune_one_graph(raw, e3, n_cuts, lim_lo, lim_hi):
+    """One graph's summary of its raw (H, C, 5) plane; see
+    :func:`_evaluate_fleet_graph_pruned`."""
+    H, C, _ = raw.shape
+    K = PRUNE_ROWS
+    real = (jnp.arange(C) < n_cuts)[None, :]
+    bad = (~jnp.isfinite(raw)) | (raw < 0.0) | (raw > _POISON_FLOOR)
+    n_poison = jnp.sum(jnp.any(bad, axis=-1) & real)
+    key, sure, over = _prune_classes(raw, e3[:, None, :], lim_lo, lim_hi)
+    sure &= real
+    undecided = real & ~sure & ~over
+    least = jnp.min(jnp.where(sure, key, jnp.inf))
+    survive = undecided | (sure & (key <= least * PRUNE_RATIO))
+    # Survivors in (h, c) order: first the hardware rows that hold any,
+    # then the first K survivors among those rows.
+    per_row = jnp.sum(survive, axis=1)
+    rows = _first_k((per_row > 0)[None, :], K)
+    rows_c = jnp.minimum(rows, H - 1)
+    sub = survive[rows_c] & (rows < H)[:, None]
+    flat = jnp.minimum(_first_k(sub, K), K * C - 1)
+    h, c = rows_c[flat // C], flat % C
+    got = raw[h, c]
+    _, got_sure, _ = _prune_classes(got, e3[h], lim_lo, lim_hi)
+    return PlaneSummary(
+        n_poison=n_poison,
+        n_sure=jnp.sum(sure),
+        n_undecided=jnp.sum(undecided),
+        n_survivors=jnp.sum(per_row),
+        h=h.astype(jnp.int32),
+        c=c.astype(jnp.int32),
+        sure=got_sure,
+        rows=got,
+    )
+
+
+def _evaluate_fleet_graph_pruned(
+    feat, esrc, edst, ewords, src_mask, sink_mask, cuts_batch, hw_rows,
+    area_consts, node_mask, edge_mask,
+    n_cuts: jnp.ndarray,  # (G,) int32 — real cut rows of each graph
+    lim_lo: jnp.ndarray,  # (4,) float32, from prune_limits
+    lim_hi: jnp.ndarray,  # (4,) float32
+):
+    """The fleet sweep and, in the same program, a summary per graph of the
+    rows that can still win -> (raw (G, H, C, 5), summary).
+
+    Over the real cells of each graph (cut rows below ``n_cuts``) the
+    summary counts the cells the finite guard may flag (``n_poison``), and
+    classes each cell by its float32 columns against the limits widened by
+    :data:`PRUNE_DELTA`: surely feasible (``n_sure``), surely infeasible,
+    or undecided (``n_undecided``).  The survivors are every undecided cell
+    and every surely-feasible cell whose key is within
+    :data:`PRUNE_RATIO` of the least surely-feasible key; their count is
+    ``n_survivors``, and the first :data:`PRUNE_ROWS` of them in (h, c)
+    order come back as raw float64 ``rows`` with their ``h``, ``c`` and
+    whether each is surely feasible.
+
+    Why the survivors hold the winner: the exact feasible set holds every
+    surely-feasible cell, so the winner's energy is at most that of the
+    cell with the least surely-feasible key, and the key is within
+    ``PRUNE_DELTA`` of the energy on both sides.  Every cell tied with the
+    winner survives for the same reason, so the host's tie order sees all
+    of them.
+    """
+    raw = _evaluate_fleet_graph(
+        feat, esrc, edst, ewords, src_mask, sink_mask, cuts_batch, hw_rows,
+        area_consts, node_mask, edge_mask,
+    )
+    e3 = hw_rows[:, H_EDRAM:H_EPB + 1]
+    summary = jax.vmap(_prune_one_graph, in_axes=(0, None, 0, None, None))(
+        raw, e3, n_cuts, lim_lo, lim_hi)
+    return raw, summary
+
+
+_jit_fleet_graph_pruned = jax.jit(_evaluate_fleet_graph_pruned)
 
 
 # Per-mesh jitted shard_map wrappers around the fleet kernel.  Meshes are

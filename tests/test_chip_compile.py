@@ -9,6 +9,8 @@ chip would refuse fails here without one:
 * the fleet kernel at the zoo co-search shapes (qwen3-0.6b and
   phi3-mini-3.8b superblocks at seq_len 512 x the 2560-point grid);
 * the same kernel shard_mapped over a 4-device ``hardware`` mesh;
+* the pruned fleet program at the exhaustive co-search's shape (VGG-16,
+  one 4096-grouping slice, the 2560-point grid);
 * the four Pallas kernels at the blocks ``planner.plan_model`` picks for
   qwen3-0.6b, with ``interpret=False``.
 
@@ -28,9 +30,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import REGISTRY
 from repro.core import flow
 from repro.core import metrics as M
-from repro.core.arch import TPU_V5E, config_space_grid
+from repro.core.arch import TPU_V5E, Constraints, config_space_grid
 from repro.core.frontend import transformer_graph
-from repro.core.ir import bucket_size, pad_graph
+from repro.core.ir import as_graph, bucket_size, pad_graph, vgg16_ir
 from repro.core.planner import plan_model
 from repro.kernels import (
     VMEM_LIMIT_BYTES, fused_attention, fused_conv, fused_mlp, mamba_scan,
@@ -101,6 +103,30 @@ def test_fleet_kernel_compiles_for_one_chip(one_chip, fleet_args):
         specs = _specs(fleet_args, [one_chip] * len(fleet_args))
         compiled = M._jit_fleet_graph.lower(*specs).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+def test_pruned_fleet_program_compiles_for_one_chip(one_chip):
+    g = as_graph(vgg16_ir(pool_mode="separate"))
+    pg = pad_graph(g, n_nodes=flow.NODE_BUCKET_FLOOR,
+                   n_edges=flow.EDGE_BUCKET_FLOOR)
+    grid = config_space_grid()
+    G, H, C = 1, len(grid), 4096
+    args = tuple(np.asarray(a)[None] for a in (
+        pg.feat, pg.esrc, pg.edst, pg.ewords, pg.src_mask, pg.sink_mask,
+        np.zeros((C, pg.n_edges_padded), bool)))
+    args += (np.stack([c.as_row() for c in grid]),
+             M.area_consts_of_space(grid), pg.node_mask[None],
+             pg.edge_mask[None], np.array([C], np.int32),
+             *M.prune_limits(Constraints().as_row()))
+    with jax.enable_x64(True):
+        specs = _specs(args, [one_chip] * len(args))
+        compiled = M._jit_fleet_graph_pruned.lower(*specs).compile()
+    mem = compiled.memory_analysis()
+    plane = G * H * C * 5 * 8
+    # the raw plane and a summary of a few KB; the pruning's own buffers
+    # stay small beside the plane
+    assert plane < mem.output_size_in_bytes < plane + 2**16
+    assert mem.temp_size_in_bytes < 1.1 * plane
 
 
 def test_sharded_fleet_kernel_compiles_for_four_chips(topo, fleet_args):

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.core import flow, spans
+from repro.core import metrics as M
 from repro.core.arch import Constraints, config_space_grid
 from repro.core.errors import RetryPolicy
 from repro.core.ir import as_graph, residual_block_ir
@@ -128,8 +129,10 @@ def test_run_fleet_records_one_call_with_each_stage():
     assert _names(kids2) == sorted(STAGES)  # an executable-cache hit
     assert all(r.parent_id == root.span_id for r in kids)
     fetch = next(r for r in kids if r.name == "fleet.fetch")
-    G, H, C = 1, len(GRID), flow.CUT_BUCKET_FLOOR
-    assert fetch.work == G * H * C * 5 * 8  # the raw f64 plane's bytes
+    # the pruned program's summary, not the raw (1, 48, 4, 5) f64 plane:
+    # four int64 counts and K survivor rows of (h, c, sure, 5 f64 words)
+    G, K = 1, M.PRUNE_ROWS
+    assert fetch.work == G * (4 * 8 + K * (4 + 4 + 1 + 5 * 8))
     by = {r.name: r for r in kids}
     assert fl.compile_seconds == by["fleet.compile"].seconds
     assert fl.sweep_seconds == (by["fleet.execute"].seconds
