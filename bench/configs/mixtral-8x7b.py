@@ -1,7 +1,12 @@
 """Mixtral-8x7B: one decoder period (GQA attention + 8-expert top-2 SwiGLU
-MoE) as the program traces it from its JAX transformer, and the checks the
-reference makes of that trace against the published sizes."""
+MoE) as the program traces it from its JAX transformer, and the plain
+reference's own layer and edge table of that period, written from the
+published sizes."""
 from __future__ import annotations
+
+import math
+
+import numpy as np
 
 
 def program_config(model: dict):
@@ -28,32 +33,67 @@ def program_graph(model: dict, seq_len: int = 512):
     return transformer_graph(program_config(model), seq_len=seq_len)
 
 
-def reference_graph(model: dict, ref, program_graph, **_shape):
-    """The reference evaluates the trace's layer geometry and edges; it
-    recounts every cost itself (``trace_diffs`` holds the trace to the
-    published sizes)."""
-    return ref.plain_from_program(program_graph)
+def reference_graph(config: dict, ref, seq_len: int = 512, **_shape):
+    """One decoder period at prefill length ``seq_len``, batch 1, written
+    node by node from ``config["model"]`` and ``config["routing"]``; the
+    program's trace is not read.
 
-
-def trace_diffs(config: dict, program_graph, ref_graph, ref,
-                seq_len: int = 512) -> int:
-    """How far the trace departs from the published layer: the weight
-    words of one decoder layer (norms aside), the expert and router
-    shapes, and the traced prefill length."""
-    m = config["model"]
+    The cost model's notation: a node is (kind, n_in, n_out, h_in) with
+    w_in 1; a matmul contracts n_in against a weight to n_out features at
+    h_in rows; an activation product (``actmul``) folds its batch axes, the
+    heads or the routing groups, into the contraction and the output, so
+    one node prices them all; an elementwise op of one operand (norms,
+    rotary, softmax, SiLU) folds into the node that produces its input, and
+    one that joins two activations is a node of its own.  Keys and values
+    reach the score and value products repeated to every query head.  An
+    edge carries the words its consumer reads.
+    """
+    m, r = config["model"], config["routing"]
+    S = seq_len
     d, ff = m["hidden_size"], m["intermediate_size"]
-    heads, kv = m["num_attention_heads"], m["num_key_value_heads"]
-    hd = d // heads
-    experts = m["num_local_experts"]
-    want_w = (d * heads * hd + 2 * d * kv * hd + heads * hd * d
-              + d * experts + experts * 3 * d * ff)
-    layers = ref_graph.layers
-    weighted = [l for l in layers if l.kind in ref.WEIGHTED]
-    got_w = sum(l.n_in // l.groups * l.kh * l.kw * l.n_out for l in weighted)
-    n_expert = sum(1 for l in weighted if {l.n_in, l.n_out} == {d, ff})
-    n_router = sum(1 for l in weighted if (l.n_in, l.n_out) == (d, experts))
-    diffs = int(got_w != want_w) + abs(n_expert - 3 * experts) \
-        + abs(n_router - 1)
-    diffs += int(max(l.h_in for l in layers) < seq_len)
-    return diffs + ref.graph_diffs(ref_graph,
-                                   ref.plain_from_program(program_graph))
+    H, KV = m["num_attention_heads"], m["num_key_value_heads"]
+    hd = d // H
+    E, k = m["num_local_experts"], m["num_experts_per_tok"]
+    G = min(r["group_size"], S)  # tokens routed together
+    n_groups = S // G
+    C = math.ceil(k * G / E * r["capacity_factor"])  # slots an expert a group
+    T = n_groups * C  # rows an expert computes
+    L = ref.Layer
+    layers = [
+        L("matmul", d, H * hd, S, 1),  # 0 query projection
+        L("matmul", d, KV * hd, S, 1),  # 1 key projection
+        L("matmul", d, KV * hd, S, 1),  # 2 value projection
+        L("actmul", H * hd, H * S, S, 1),  # 3 scores Q K^T, softmax folded
+        L("actmul", H * S, H * S, hd, 1),  # 4 scores x V
+        L("matmul", H * hd, d, S, 1),  # 5 output projection
+        L("elementwise", d, d, S, 1, ext_in_words=S * d),  # 6 residual
+        L("matmul", d, E, S, 1),  # 7 router
+        L("actmul", S, n_groups * d, E * C, 1),  # 8 dispatch to the slots
+    ]
+    w1 = len(layers)  # E gate projections, then E up projections
+    layers += [L("matmul", d, ff, T, 1)] * (2 * E)
+    gate = len(layers)  # SiLU(gate) x up
+    layers += [L("elementwise", ff, ff, T, 1)] * E
+    w2 = len(layers)  # down projections
+    layers += [L("matmul", ff, d, T, 1)] * E
+    combine = len(layers)
+    layers += [L("actmul", n_groups * E * C, n_groups * d, G, 1),
+               L("elementwise", d, d, S, 1)]  # residual
+    q_words = S * H * hd
+    edges = [(0, 3, q_words), (1, 3, q_words), (2, 4, q_words),
+             (3, 4, H * S * S), (4, 5, q_words), (5, 6, S * d),
+             (6, 7, S * d), (6, 8, S * d), (6, combine + 1, S * d),
+             (7, 8, S * E * C), (7, combine, S * E * C),
+             (combine, combine + 1, S * d)]
+    for e in range(E):
+        edges += [(8, w1 + e, T * d), (8, w1 + E + e, T * d),
+                  (w1 + e, gate + e, T * ff), (w1 + E + e, gate + e, T * ff),
+                  (gate + e, w2 + e, T * ff), (w2 + e, combine, T * d)]
+    return ref.PlainGraph(f"mixtral-8x7b.s{S}", tuple(layers),
+                          np.asarray(sorted(edges), np.int64))
+
+
+def trace_diffs(config: dict, program_graph, ref_graph, ref, **_shape) -> int:
+    """Fields in which the program's trace differs from the reference's
+    table: every node's geometry and every edge's endpoints and words."""
+    return ref.graph_diffs(ref_graph, ref.plain_from_program(program_graph))

@@ -11,10 +11,11 @@ def program_graph(model: dict, **_shape):
                              include_fc=model["include_fc"]))
 
 
-def reference_graph(model: dict, ref, program_graph=None, **_shape):
+def reference_graph(config: dict, ref, **_shape):
     """Configuration D written out layer by layer: 3x3 SAME convolutions at
     stride 1, each stage closed by a 2x2 max-pool of stride 2.  Built from
     the published table alone; the program's trace is not read."""
+    model = config["model"]
     hw, c_in = model["input_hw"], model["input_channels"]
     k, p = model["conv_kernel"], model["pool_window"]
     layers = []

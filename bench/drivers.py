@@ -270,8 +270,7 @@ class SliceLoop(Driver):
 
     def check(self, control: bool = False) -> dict:
         ch = Checks()
-        ref_g = self.config_mod.reference_graph(self.config["model"], R,
-                                                self.g)
+        ref_g = self.config_mod.reference_graph(self.config, R)
         ch.count("inputs_diffs", self.config_mod.trace_diffs(
             self.config, self.g, ref_g, R))
         hw_ref, area = self.ref_space()
@@ -429,7 +428,7 @@ class PlanLoop(Driver):
             k = (p["seq_len"], p["batch"].tobytes())
             if k not in cache:
                 ref_g = self.config_mod.reference_graph(
-                    self.config["model"], R, p["g"], seq_len=p["seq_len"])
+                    self.config, R, seq_len=p["seq_len"])
                 diffs = self.config_mod.trace_diffs(
                     self.config, p["g"], ref_g, R, seq_len=p["seq_len"])
                 ev = R.Evaluator(ref_g, hw_ref, area)
@@ -538,8 +537,7 @@ class ServeLoop(Driver):
         hw_ref, area = self.ref_space()
         ev0 = R.Evaluator(
             self.config_mod.reference_graph(
-                self.config["model"], R, self.graphs[0],
-                seq_len=self.seq_lens[0]), hw_ref, area)
+                self.config, R, seq_len=self.seq_lens[0]), hw_ref, area)
         self.caps = area_caps(ev0, t, self.seed)
         self.deadline = float(t["deadline_s"])
         self.opts = dict(t.get("service", {}))
@@ -647,8 +645,8 @@ class ServeLoop(Driver):
         ch.count("picks_wrong", self.counters["unanswered"])
         per_len = {}
         for si, s in enumerate(self.seq_lens):
-            ref_g = self.config_mod.reference_graph(
-                self.config["model"], R, self.graphs[si], seq_len=s)
+            ref_g = self.config_mod.reference_graph(self.config, R,
+                                                    seq_len=s)
             ch.count("inputs_diffs", self.config_mod.trace_diffs(
                 self.config, self.graphs[si], ref_g, R, seq_len=s))
             ev = R.Evaluator(ref_g, hw_ref, area)

@@ -228,7 +228,11 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     trace_dir = pathlib.Path(root).resolve().parent / ".bench_trace" / cell
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
-        jax.profiler.start_trace(str(trace_dir))
+        # The harness's spans are TraceAnnotations, which the host tracer
+        # keeps; a Python tracer would slow the host path it measures.
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
     setup_s = time.perf_counter() - t_start
     try:
         with spans.span("bench.window"):

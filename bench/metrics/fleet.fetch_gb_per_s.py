@@ -1,6 +1,7 @@
-"""Rate of the device-to-host copy of the sweep's raw plane: the bytes of
-the program's ``fleet.fetch`` spans over their time, summed over the
-``fleet.call`` spans that began in the window."""
+"""Rate of the device-to-host copy of what the sweep returns (the pruned
+program's summary, or the raw plane): the bytes of the program's
+``fleet.fetch`` spans over their time, summed over the ``fleet.call`` spans
+that began in the window."""
 from program_spans import stage_per_call
 
 

@@ -1,8 +1,11 @@
-"""The fleet sweep kernel's share of its HBM roofline: the least time of
-the bytes its shapes require (``roofline.sweep_bytes``) at the chip's
-published HBM bandwidth, over the sweep program's device time.  Bound
-named: HBM.  No published peak exists for emulated float64 arithmetic, so
-the share has no compute bound."""
+"""The fleet sweep program's share of its HBM floor: the least time of the
+bytes any implementation must move (``roofline.sweep_bytes``: the inputs
+once and the answer the call returns) at the chip's published HBM
+bandwidth, over the sweep program's device time.  The sweep is bound by
+emulated float64 arithmetic, which has no published peak, so the share
+has no compute bound; it is a floor that no valid implementation can
+exceed, whether it writes the candidate plane or reduces it on the
+device."""
 from readers import sweep_device_s
 from roofline import sweep_bytes
 

@@ -63,6 +63,33 @@ def test_broken_timed_path_is_not_correct(tmp_path, monkeypatch, cell, kind):
     assert res["correct"] is False, res["checks"]
 
 
+def score_edge_halved(trace):
+    """The traced graph with its largest edge (attention's S x S scores)
+    carrying half its words."""
+    import dataclasses
+
+    from repro.core.ir import EdgeSpec
+
+    def broken(*args, **kw):
+        g = trace(*args, **kw)
+        big = max(g.edges, key=lambda e: e.words)
+        edges = tuple(EdgeSpec(e.src, e.dst, e.words // 2) if e is big else e
+                      for e in g.edges)
+        return dataclasses.replace(g, edges=edges)
+    return broken
+
+
+@pytest.mark.parametrize("cell", ["mixtral-8x7b.search", "mixtral-8x7b.serve"])
+def test_broken_trace_is_not_correct(tmp_path, monkeypatch, cell):
+    from repro.core import frontend
+
+    monkeypatch.setattr(frontend, "transformer_graph",
+                        score_edge_halved(frontend.transformer_graph))
+    res = benchkit.run_small(tmp_path, cell, seconds=1.5)
+    assert res["correct"] is False, res["checks"]
+    assert res["checks"]["inputs_diffs"]["value"] > 0
+
+
 FOUR_CHIP = r"""
 import sys, tempfile
 sys.path.insert(0, sys.argv[1])

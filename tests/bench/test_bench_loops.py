@@ -1,12 +1,19 @@
 """The three loops on the CPU against a 12-point design space: rates are
 the work over the whole window, the slice merge keeps ``run_fleet``'s tie
-order, and a sound run is correct."""
+order, a sound run is correct, and every cell of the real
+``BENCHMARK.json`` reports the metrics that file names for it."""
+import json
+import time
+
 import numpy as np
 import pytest
 
 import benchkit
 import drivers
 import reference as R
+from harness import load_metric, load_spec, run_cell
+
+REAL = json.loads((benchkit.ROOT / "BENCHMARK.json").read_text())
 
 
 @pytest.mark.parametrize("cell", ["vgg16.exhaustive", "mixtral-8x7b.search",
@@ -20,9 +27,30 @@ def test_sound_run_is_correct(tmp_path, cell):
     assert list(res)[-1] == "checks"
 
 
-def test_rate_is_all_work_over_whole_window(tmp_path):
-    import time
+@pytest.mark.parametrize("cell", [w["name"] for w in REAL["workloads"]])
+def test_every_benchmark_cell_reports_its_end_to_end_metrics(tmp_path, cell):
+    """The real BENCHMARK.json's entry of ``cell`` run at CPU size: its loop
+    reports every end-to-end metric the file applies to the cell, and every
+    per-layer metric it names for the cell has a reader and moves one of
+    those."""
+    real = benchkit.ROOT / "BENCHMARK.json"
+    root = benchkit.small_root(tmp_path)
+    res = run_cell(cell, 11, 1.5, False, t_start=time.perf_counter(),
+                   bench_json=real, root=root, require_tpu=False,
+                   compile_cache=False)
+    spec = load_spec(real, cell)
+    ends = {m["name"] for m in spec.end_to_end}
+    assert "setup_s" in ends and len(ends) >= 2
+    assert set(res["metrics"]) == ends
+    assert all(m["value"] > 0 for m in res["metrics"].values())
+    assert res["correct"] is True, res["checks"]
+    assert spec.per_layer
+    for m in spec.per_layer:
+        assert m["moves"] in ends, m["name"]
+        assert callable(load_metric(m["name"], root).read)
 
+
+def test_rate_is_all_work_over_whole_window(tmp_path):
     from harness import Spans, load_config, load_traffic
 
     root = benchkit.small_root(tmp_path)

@@ -60,9 +60,22 @@ def test_p95_counts_failures_as_infinite():
 
 def test_roofline_bytes_hand_count():
     # 3 nodes, 2 edges, 5 hardware points, 4 groupings, one graph:
-    # features 3*13*8, edges 2*3*8, masks 2*3+3+2, cuts 4*2,
-    # hardware 5*11*8 + 4*8, rows 5*4*4*8.
-    want = 312 + 48 + 11 + 8 + 440 + 32 + 640
+    # features 3*13*8, edges 2*3*8, masks 2*3+3+2, cuts 4*2, the picked
+    # row 6*8, hardware 5*11*8 + 4*8.
+    want = 312 + 48 + 11 + 8 + 48 + 440 + 32
     assert sweep_bytes(3, 2, 5, 4) == want
     assert sweep_bytes(3, 2, 5, 4, n_graphs=2) == 2 * (
-        312 + 48 + 11 + 8) + 472 + 2 * 640
+        312 + 48 + 11 + 8 + 48) + 472
+
+
+def test_roofline_bytes_at_the_vgg16_slice():
+    # vgg16.exhaustive's call: 18 nodes, 17 edges, 2560 points, a slice of
+    # 4096 groupings, one graph.  Features 18*13*8 = 1872, edges 17*3*8 =
+    # 408, masks 2*18+18+17 = 71, cuts 4096*17 = 69632, the picked row 48,
+    # hardware 2560*11*8 = 225280, area constants 32.
+    assert sweep_bytes(18, 17, 2560, 4096) == 297343
+    # nothing is charged per (point x grouping) candidate
+    assert (sweep_bytes(18, 17, 2560, 4096)
+            - sweep_bytes(18, 17, 2560, 2048)) == 2048 * 17
+    assert (sweep_bytes(18, 17, 2560, 4096)
+            - sweep_bytes(18, 17, 1280, 4096)) == 1280 * 11 * 8
