@@ -15,6 +15,7 @@ from . import (  # noqa: E402
     granite_34b,
     internvl2_1b,
     jamba_1_5_large,
+    jamba2_mini,
     llama4_maverick_400b,
     mixtral_8x7b,
     phi3_mini_3_8b,
@@ -27,7 +28,7 @@ REGISTRY: dict[str, ModelConfig] = {
     for m in (
         llama4_maverick_400b, arctic_480b, internvl2_1b, granite_34b,
         phi3_mini_3_8b, gemma3_27b, qwen3_0_6b, seamless_m4t_v2,
-        jamba_1_5_large, falcon_mamba_7b, mixtral_8x7b,
+        jamba_1_5_large, falcon_mamba_7b, mixtral_8x7b, jamba2_mini,
     )
 }
 
@@ -68,6 +69,7 @@ LONG_CONTEXT_ARCHS = {
     "llama4-maverick-400b-a17b",  # 3/4 layers chunked-local 8192
     "gemma3-27b",  # 5/6 layers sliding-window 1024
     "jamba-1.5-large-398b",  # 7/8 layers Mamba
+    "jamba2-mini",  # 7/8 layers Mamba
     "falcon-mamba-7b",  # pure SSM
 }
 

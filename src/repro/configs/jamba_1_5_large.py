@@ -1,8 +1,9 @@
 """jamba-1.5-large-398b [hybrid] — 72L d_model=8192 64H (GQA kv=8)
 d_ff=24576 vocab=65536, MoE 16e top-2, Mamba:attn 7:1.  [arXiv:2403.19887]
 
-Jamba period-8 block: one attention layer (index 3) per seven Mamba
-layers; MoE replaces the dense MLP on every other layer.  72 = 9 periods.
+Jamba period-8 block: one attention layer per seven Mamba layers, at
+index 4 (JambaConfig: layer i is attention iff i % 8 == 4); MoE replaces
+the dense MLP on every other layer (i % 2 == 1).  72 = 9 periods.
 Mamba layers make the model O(state) in context => long_500k runs; the
 9 attention layers' 500k KV (batch 1) shards its sequence axis over the
 data axis (sequence parallelism).
@@ -20,7 +21,7 @@ CONFIG = ModelConfig(
     d_ff=24_576,
     vocab_size=65_536,
     layer_pattern=(
-        "mamba", "mamba", "mamba", "attn", "mamba", "mamba", "mamba", "mamba",
+        "mamba", "mamba", "mamba", "mamba", "attn", "mamba", "mamba", "mamba",
     ),
     n_experts=16,
     top_k=2,

@@ -13,8 +13,12 @@ jaxpr onto the paper's layer abstraction:
   1x1 convolution over ``M`` "pixels"; ``M == 1`` is tagged ``fc``).  A
   ``dot_general`` whose *both* operands are activations becomes ``actmul``
   (attention's QK^T / PV — the "kernel" operand is activation traffic);
-  batch dimensions fold into the contraction (``k *= B``) so one actmul
-  node prices all heads, and its O(S^2) score matrix is an explicit edge.
+  batch dimensions fold into the contraction and the output (``k *= B``,
+  ``n *= B``) so one actmul node prices all heads, and its O(S^2) score
+  matrix is an explicit edge.  Folding both prices B times the MACs of B
+  independent products; ``trace(..., batch_groups=True)`` also sets
+  :class:`LayerSpec` ``groups`` to B, so each output contracts only its
+  own batch index's ``k`` and the MAC count is that of the B products.
   Two-activation products whose operands descend from the *same* dataflow
   source (MoE's combine-weights einsum — a rearrangement of one tensor)
   fold instead of minting a bogus giant actmul.  An activation against a
@@ -76,6 +80,7 @@ import jax
 import numpy as np
 from jax.extend import core as jex_core
 
+from . import spans
 from .errors import GraphValidationError, UnsupportedOpError
 from .ir import (
     RESNET18_STAGE_PLAN,
@@ -123,9 +128,10 @@ class _Tracer:
     graph input — the original input var itself, so two different inputs
     stay two different sources (and two views of one input stay one)."""
 
-    def __init__(self, *, name: str, fold_pool: bool):
+    def __init__(self, *, name: str, fold_pool: bool, batch_groups: bool):
         self.name = name
         self.fold_pool = fold_pool
+        self.batch_groups = batch_groups
         self.nodes: list[_PendingNode] = []
         self.producer: dict[Any, Any] = {}  # activation var -> source
 
@@ -245,9 +251,11 @@ class _Tracer:
                 # MACs per output word.
                 self.producer[out] = self.producer[lhs]
                 return
-            # Attention-style activation product: the batch axes (heads)
-            # fold into the contraction/output so one node prices them all.
+            # Attention-style activation product: the batch axes (heads,
+            # routing groups) fold into the contraction/output so one node
+            # prices them all; as groups, each batch index's own.
             kind, k, m, n = "actmul", B * k, l_free, B * r_free
+            groups = B if self.batch_groups else 1
         elif B > 1:
             # One activation against a stacked weight tensor (MoE expert
             # einsums, (E, d, ff)): E independent matmuls — expand into B
@@ -288,6 +296,7 @@ class _Tracer:
         else:
             m, n = (l_free, r_free) if lhs_is_act else (r_free, l_free)
             kind = "fc" if m == 1 else "matmul"
+            groups = 1
         # A graph-input operand of a non-source node (e.g. actmul of a
         # projected query against the raw input) has no edge to fuse over:
         # its words stream from DRAM in every grouping.  Source nodes
@@ -295,7 +304,8 @@ class _Tracer:
         has_edge = any(isinstance(p, (int, tuple)) for _, p in act_in)
         ext = self._ext_words(act_in) if has_edge else 0
         spec = LayerSpec(
-            f"{kind}{len(self.nodes)}", kind, k, n, m, 1, ext_in_words=ext
+            f"{kind}{len(self.nodes)}", kind, k, n, m, 1, groups=groups,
+            ext_in_words=ext,
         )
         if _words(out.aval) != m * n:
             raise UnsupportedOpError(
@@ -486,14 +496,43 @@ class _Tracer:
                 self.eqn_scan(eqn, act_in)
             else:
                 self.eqn_default(eqn, act_in)
-        if not self.nodes:
+        live = self._live_nodes(jaxpr.outvars)
+        if not live:
             raise UnsupportedOpError(f"{self.name}: no layers traced")
+        new_id = {old: new for new, old in enumerate(live)}
+        specs = []
+        for old in live:
+            spec = self.nodes[old].spec
+            if len(live) < len(self.nodes):  # renumbered: rename to match
+                stem = spec.name.rstrip("0123456789")
+                spec = dataclasses.replace(spec, name=f"{stem}{new_id[old]}")
+            specs.append(spec)
         edges = tuple(
-            EdgeSpec(src, dst, words)
-            for dst, node in enumerate(self.nodes)
-            for src, words in sorted(node.inputs.items())
+            EdgeSpec(new_id[src], new_id[dst], words)
+            for dst in live
+            for src, words in sorted(self.nodes[dst].inputs.items())
         )
-        return GraphIR(self.name, tuple(n.spec for n in self.nodes), edges)
+        return GraphIR(self.name, tuple(specs), edges)
+
+    def _live_nodes(self, outvars) -> list[int]:
+        """Ids (ascending) of the nodes from which a traced output can be
+        reached.  ``make_jaxpr`` keeps dead equations (a loss term the
+        function computes and drops); a node that feeds no output would
+        only add a sink write to every grouping, so it is left out."""
+        stack = []
+        for v in outvars:
+            if isinstance(v, jex_core.Literal) or v not in self.producer:
+                continue
+            p = self.producer[v]
+            stack.extend(p if isinstance(p, tuple) else
+                         [p] if isinstance(p, int) else [])
+        live: set[int] = set()
+        while stack:
+            i = stack.pop()
+            if i not in live:
+                live.add(i)
+                stack.extend(self.nodes[i].inputs)
+        return sorted(live)
 
 
 def trace(
@@ -503,6 +542,7 @@ def trace(
     activation_argnums: Sequence[int] | None = None,
     fold_pool: bool = False,
     names: Sequence[str] | None = None,
+    batch_groups: bool = False,
 ) -> GraphIR:
     """Trace ``fn(*args)`` into a :class:`GraphIR`.
 
@@ -512,7 +552,12 @@ def trace(
     x)``); activations must be traced with batch size 1.  ``fold_pool``
     absorbs a window == stride pooling into its producing conv's
     ``pool_after`` when the pooled tensor has no other consumer.  ``names``
-    optionally renames the nodes (length-checked).
+    optionally renames the nodes (length-checked).  ``batch_groups`` prices
+    a product of two activations with batch axes (attention heads, MoE
+    routing groups) as ``groups`` = B, at the MACs of B products; the
+    default folds the batch axes into both the contraction and the output,
+    B times that (ROADMAP Reach 2b), and stays until the benchmark's
+    reference tables move with it.
 
     Example — a gated MLP, weights as shape structs only::
 
@@ -533,7 +578,20 @@ def trace(
     raises :class:`repro.core.errors.UnsupportedOpError` (a subclass of
     ``ValueError``), never a raw ``KeyError``/``IndexError`` — the planning
     service's admission path relies on this contract.
+
+    Nodes from which no traced output can be reached (a loss term the
+    function computes and drops) are left out.  Runs in a
+    ``frontend.trace`` span whose ``work`` is the node count.
     """
+    with spans.span("frontend.trace") as sp:
+        g = _trace(fn, args, name, activation_argnums, fold_pool, names,
+                   batch_groups)
+        sp.work = g.n_nodes
+    return g
+
+
+def _trace(fn, args, name, activation_argnums, fold_pool, names,
+           batch_groups) -> GraphIR:
     if not args:
         raise UnsupportedOpError("trace() needs at least one example argument")
     nums = (
@@ -553,7 +611,7 @@ def trace(
             f"{name}: fn is not traceable to a jaxpr "
             f"({type(e).__name__}: {e})"
         ) from e
-    tr = _Tracer(name=name, fold_pool=fold_pool)
+    tr = _Tracer(name=name, fold_pool=fold_pool, batch_groups=batch_groups)
     invars = iter(closed.jaxpr.invars)
     for i, arg in enumerate(args):
         leaves = jax.tree_util.tree_leaves(arg)
@@ -746,7 +804,8 @@ def _zoo_seq_len(cfg, seq_len: int) -> int:
 
 def transformer_graph(cfg, *, seq_len: int = 512,
                       n_sublayers: int | None = None,
-                      name: str | None = None) -> GraphIR:
+                      name: str | None = None,
+                      batch_groups: bool = False) -> GraphIR:
     """One superblock (``cfg.pattern_period`` sublayers) of the config's
     decoder trunk, traced from the real :mod:`repro.models.transformer`
     forward pass via :func:`~repro.models.transformer.block_forward`.
@@ -757,7 +816,9 @@ def transformer_graph(cfg, *, seq_len: int = 512,
     ``state_words``; MoE sublayers expand into router + E expert branches +
     combine.  ``n_sublayers`` overrides the traced depth (default: one full
     pattern period, so jamba's 1:7 attn:mamba interleave and llama4's
-    alternating dense/MoE both appear once)."""
+    alternating dense/MoE both appear once).  ``batch_groups`` is
+    :func:`trace`'s: the attention and routing products at the MACs of one
+    product per head or routing group."""
     from ..configs.base import RunConfig
     from ..models import transformer as T
 
@@ -772,6 +833,7 @@ def transformer_graph(cfg, *, seq_len: int = 512,
         params,
         _sds(1, seq_len, cfg.d_model),
         name=name or f"{cfg.name}.block",
+        batch_groups=batch_groups,
     )
 
 

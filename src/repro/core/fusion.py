@@ -56,13 +56,20 @@ Strategies, all returning cut vectors compatible with
   canonical label state, and scores it with one batched validity /
   feasibility / bandwidth pass.  Cross-checked against brute force on
   random DAGs in tests.
+* ``segment_dp_min_bw``   — exact search past the frontier-width cap: the
+  DAG is split at single-node separators (the residual adds between
+  sublayers), each segment is solved on its own (frontier DP, or a bundle
+  of isomorphic branches — MoE experts — solved by counts) and memoised
+  under a key of its shape.  See the section comment above it.
 * ``optimal_cuts`` — dispatch: chain DP fast path, frontier DP (exact, up
   to a frontier-width cap), exhaustive enumeration for small-but-wide
-  DAGs, beam search only for large-and-wide ones; results carry ``engine``
-  provenance so callers can tell certified optima from heuristics.
+  DAGs, the segment DP for large-and-wide ones, beam search only where
+  that declines; results carry ``engine`` provenance so callers can tell
+  certified optima from heuristics.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import Iterator
@@ -70,6 +77,7 @@ from typing import Iterator
 import numpy as np
 
 from .ir import (
+    EdgeSpec,
     GraphIR,
     NetworkIR,
     as_graph,
@@ -83,6 +91,7 @@ from .ir import (
     _min_label_reps_batch,
 )
 from . import metrics as M
+from . import spans
 from .errors import InfeasibleBudgetError, SearchDeclined
 
 MAX_EXHAUSTIVE_LAYERS = 21  # 2^20 cut vectors ~ 1M candidates (vectorised)
@@ -105,6 +114,9 @@ SMALL_ENUM_PATTERNS = 64
 # the caps only trip on adversarially dense random graphs.
 FRONTIER_DP_MAX_WIDTH = 12
 FRONTIER_DP_MAX_STATES = 1 << 17
+# Segment solutions kept by the segment DP's memo (one entry per distinct
+# segment shape and budget; a traced period has a handful of shapes).
+SEGMENT_MEMO_SIZE = 256
 
 
 class FrontierTooWide(SearchDeclined):
@@ -429,15 +441,18 @@ class DPResult:
     group_cost_words: float  # Eq. (1) minus the grouping-independent weights
     n_groups: int
     # Which engine produced the answer ("chain_dp", "frontier_dp",
-    # "exhaustive", "greedy", "beam", ...) and whether the result carries an
-    # optimality guarantee — the provenance `optimal_cuts` callers use to
-    # tell an exact optimum from a heuristic.
+    # "segment_dp", "exhaustive", "greedy", "beam", ...) and whether the
+    # result carries an optimality guarantee — the provenance
+    # `optimal_cuts` callers use to tell an exact optimum from a heuristic.
     engine: str = ""
+    # DP states the engine kept over its sweep (0 where it counts none).
+    states: int = 0
 
     @property
     def exact(self) -> bool:
         """True when the engine certifies a global optimum."""
-        return self.engine in ("chain_dp", "frontier_dp", "exhaustive")
+        return self.engine in ("chain_dp", "frontier_dp", "segment_dp",
+                               "exhaustive")
 
 
 def optimal_cuts_dp(
@@ -761,6 +776,7 @@ def frontier_dp_min_bw(
     states: "dict[tuple, _DPState]" = {
         ((), 0, ()): _DPState((), 0, (), 0.0, np.zeros(E, dtype=bool))
     }
+    kept = 0
     for t, v in enumerate(order):
         frontier = frontiers[t - 1] if t else []
         pos_of = {u: i for i, u in enumerate(frontier)}
@@ -898,6 +914,7 @@ def frontier_dp_min_bw(
                 f"{len(new_states)} live states exceed the DP cap {max_states}"
             )
         states = new_states
+        kept += len(states)
 
     best = min(states.values(), key=lambda s: s.acc)
     labels = cut_group_labels(g, best.cuts)
@@ -906,6 +923,7 @@ def frontier_dp_min_bw(
         group_cost_words=const0 + best.acc,
         n_groups=int(labels.max()) + 1,
         engine="frontier_dp",
+        states=kept,
     )
 
 
@@ -921,6 +939,380 @@ def _frontier_dp_cached(g: GraphIR, sram_budget_words: float) -> "DPResult | Non
         return frontier_dp_min_bw(g, sram_budget_words=sram_budget_words)
     except FrontierTooWide:
         return None
+
+
+# ---------------------------------------------------------------------------
+# Segment DP — exact search past the frontier-width cap
+# ---------------------------------------------------------------------------
+#
+# A decoder period is a series of sublayers joined at residual adds, and
+# each such add is a *single-node separator* of the natural (traced) node
+# order: no edge jumps over it.  Every term of the search decomposes at a
+# separator v:
+#
+# * cost — an edge is decided in the segment of its producer, and a node's
+#   output write is charged by its own out-edges, which all lie in one
+#   segment (v's in the segment after v);
+# * SRAM feasibility — a max of per-node terms, each fixed by one node's
+#   in-edges or its out-edges;
+# * validity — a group with nodes on both sides of v contains v (groups are
+#   connected, and every connection across v runs through v), so a path
+#   that leaves a group and re-enters it never passes v in its interior:
+#   every quotient cycle, and every cut edge inside a group, shows within
+#   one segment.
+#
+# The optimum is therefore the concatenation of per-segment optima, each
+# segment a graph of its own whose first node is a source and last node a
+# sink (constant shifts of its cost).  A segment within the frontier DP's
+# caps is solved by it.  A wider segment is solved exactly when its width
+# comes from a *bundle*: k >= 2 isomorphic branches between one node D
+# (every edge into a branch comes from D) and one node C (each branch has
+# one edge out, into C), as MoE experts sit between dispatch and combine.
+#
+# A bundle reduces to its *rest* (the segment without the branches) plus one
+# virtual edge D -> C standing for all of them.  All branch edges uncut is
+# D and C in one group (the virtual edge uncut).  Any branch edge cut puts
+# D and C in different groups (every branch lies on a D -> C path, so a
+# group holding both holds every branch whole): the virtual edge is cut,
+# each branch is grouped on its own with no uncut D ~> C path, and its
+# quotient arcs add exactly D ~> C.  So a grouping is valid iff the rest's
+# is and each branch's is, on the branch alone between D and C.  The
+# branches couple only through counts: D's output write (paid once if any
+# D edge is cut) and C's fused input sum (the words of the uncut exits).
+# One branch's table of least costs by (some D edge cut, exit uncut) and an
+# enumeration of the rest give the optimum over all k branches by counts,
+# never by identity.
+#
+# Segments are memoised under a key of their node features, edges and the
+# budget, never as a whole graph: the split and the composition run on every
+# search, while isomorphic sublayers, and repeated plans, share one solve.
+
+_SEGMENT_MEMO: "collections.OrderedDict[tuple, tuple]" = (
+    collections.OrderedDict()
+)
+
+
+def single_node_separators(g: GraphIR) -> list[int]:
+    """Nodes ``v`` of the natural order that no edge jumps over: every edge
+    out of a node before ``v`` ends at or before ``v``.  The first and the
+    last node always qualify."""
+    ga = M.graph_arrays(g)
+    far = np.full(len(g.nodes), -1, dtype=np.int64)
+    np.maximum.at(far, ga.esrc, ga.edst)
+    before = np.concatenate([[-1], np.maximum.accumulate(far)[:-1]])
+    return [int(v) for v in np.flatnonzero(before <= np.arange(len(far)))]
+
+
+def _subgraph(g: GraphIR, name: str, nodes, edge_idx, extra=()) -> GraphIR:
+    """The graph of ``nodes`` (ascending ids of ``g``) with ``g``'s edges
+    ``edge_idx``, renumbered, plus ``extra`` (src, dst, words) in ``g``'s
+    ids."""
+    loc = {int(n): i for i, n in enumerate(nodes)}
+    edges = [(g.edges[k].src, g.edges[k].dst, g.edges[k].words)
+             for k in edge_idx] + list(extra)
+    return GraphIR(name, tuple(g.nodes[int(n)] for n in nodes),
+                   tuple(EdgeSpec(loc[s], loc[t], int(w))
+                         for s, t, w in edges))
+
+
+@dataclasses.dataclass(frozen=True)
+class _Bundle:
+    """k >= 2 isomorphic branches (node ids, ascending) between ``d`` and
+    ``c``: every edge into a branch comes from ``d``, and each branch has
+    exactly one edge out, into ``c``."""
+
+    d: int
+    c: int
+    branches: tuple[tuple[int, ...], ...]
+
+
+def _find_bundle(g: GraphIR) -> "_Bundle | None":
+    """The bundle of ``g`` with the most branches (then the most nodes), or
+    None.  Branches match when their node features, internal edges and
+    edges to D and C agree position by position."""
+    ga = M.graph_arrays(g)
+    L = len(g.nodes)
+    esrc, edst = ga.esrc.tolist(), ga.edst.tolist()
+    ewords = ga.ewords.tolist()
+    rows = [tuple(r) for r in ga.feat.tolist()]
+    outdeg = np.bincount(ga.esrc, minlength=L)
+    indeg = np.bincount(ga.edst, minlength=L)
+    direct = set(zip(esrc, edst))
+    best, best_key = None, (2, 0)
+    for d in np.argsort(-outdeg, kind="stable").tolist():
+        for c in np.argsort(-indeg, kind="stable").tolist():
+            if min(outdeg[d], indeg[c]) < best_key[0]:
+                break  # k branches need k edges out of D and into C
+            if c <= d or (d, c) in direct:
+                continue
+            parent = list(range(L))
+
+            def find(x):
+                while parent[x] != x:
+                    parent[x] = parent[parent[x]]
+                    x = parent[x]
+                return x
+
+            for s, t in zip(esrc, edst):
+                if s not in (d, c) and t not in (d, c):
+                    parent[find(s)] = find(t)
+            comp: dict[int, list] = collections.defaultdict(list)
+            for n in range(L):
+                if n not in (d, c):
+                    comp[find(n)].append(n)
+            # per component: internal edges, D edges, exits, or disqualified
+            info = {r: [[], [], [], True] for r in comp}
+            for s, t, w in zip(esrc, edst, ewords):
+                rs = None if s in (d, c) else find(s)
+                rt = None if t in (d, c) else find(t)
+                if rs is not None and rs == rt:
+                    info[rs][0].append((s, t, w))
+                elif s == d and rt is not None:
+                    info[rt][1].append((t, w))
+                elif t == c and rs is not None:
+                    info[rs][2].append((s, w))
+                else:
+                    for r in (rs, rt):
+                        if r is not None:
+                            info[r][3] = False
+            groups: dict[tuple, list] = collections.defaultdict(list)
+            for r, nodes in comp.items():
+                internal, dedges, exits, ok = info[r]
+                if not ok or not dedges or len(exits) != 1:
+                    continue
+                idx = {n: i for i, n in enumerate(nodes)}
+                sig = (tuple(rows[n] for n in nodes),
+                       tuple(sorted((idx[s], idx[t], w)
+                                    for s, t, w in internal)),
+                       tuple(sorted((idx[t], w) for t, w in dedges)),
+                       (idx[exits[0][0]], exits[0][1]))
+                groups[sig].append(tuple(nodes))
+            for branches in groups.values():
+                key = (len(branches), len(branches) * len(branches[0]))
+                if len(branches) >= 2 and key > best_key:
+                    best, best_key = _Bundle(d, c, tuple(sorted(branches))), key
+    return best
+
+
+def _bundle_min_bw(g: GraphIR, b: _Bundle, budget: float):
+    """Exact min-bandwidth cuts of ``g`` with bundle ``b`` (see the section
+    comment): returns (cuts, candidates scored).  Raises
+    :class:`SearchDeclined` when the branch or the rest is past the
+    enumeration wall, :class:`InfeasibleBudgetError` when nothing fits."""
+    ga = M.graph_arrays(g)
+    L, E = len(g.nodes), g.n_edges
+    k = len(b.branches)
+    owner = np.full(L, -1)
+    for i, nodes in enumerate(b.branches):
+        owner[list(nodes)] = i
+    pos = {b.d: 0, b.c: len(b.branches[0]) + 1}
+
+    def branch_edges(nodes):
+        """Global edge ids of one branch, in its local (src, dst) order."""
+        loc = {**pos, **{n: i + 1 for i, n in enumerate(nodes)}}
+        mine = [e for e in range(E) if owner[ga.esrc[e]] == owner[nodes[0]]
+                or owner[ga.edst[e]] == owner[nodes[0]]]
+        return sorted(mine, key=lambda e: (loc[int(ga.esrc[e])],
+                                           loc[int(ga.edst[e])]))
+
+    # --- one branch alone between D and C --------------------------------
+    m = len(b.branches[0])
+    bedges = [branch_edges(nodes) for nodes in b.branches]
+    sb = _subgraph(g, "bundle.branch", [b.d, *b.branches[0], b.c], bedges[0])
+    if sb.n_edges > MAX_EXHAUSTIVE_EDGES:
+        raise SearchDeclined(
+            f"bundle branch has {sb.n_edges} edges, past the enumeration "
+            f"wall {MAX_EXHAUSTIVE_EDGES}")
+    sga = M.graph_arrays(sb)
+    pats = enumerate_valid_edge_cuts(sb)
+    lab = _min_label_reps_batch(len(sb.nodes), sga.esrc, sga.edst, pats)
+    through = lab[:, 0] == lab[:, m + 1]
+    fits = graph_max_intermediate_batch(sb, pats) <= budget
+    cutf = pats.astype(np.float64)
+    writes = (cutf @ sga.inc_src)[:, 1:m + 1] > 0.0
+    local = cutf @ sga.ewords + writes @ sga.feat[1:m + 1, M.F_OUT]
+    dcut = pats[:, sga.esrc == 0].any(axis=1)
+    exit_k = int(np.flatnonzero(sga.edst == m + 1)[0])
+    exit_uncut = ~pats[:, exit_k]
+    wx = float(sga.ewords[exit_k])
+    # table[dc][xu]: least local cost (and its pattern) of a branch not
+    # joining D to C, by (some D edge cut, exit uncut)
+    table = [[(np.inf, None), (np.inf, None)] for _ in range(2)]
+    for dc in (0, 1):
+        for xu in (0, 1):
+            sel = np.flatnonzero(fits & ~through & (dcut == bool(dc))
+                                 & (exit_uncut == bool(xu)))
+            if sel.size:
+                j = sel[np.argmin(local[sel])]
+                table[dc][xu] = (float(local[j]), pats[j])
+    all_in = bool(graph_max_intermediate_batch(
+        sb, np.zeros((1, sb.n_edges), bool))[0] <= budget)
+
+    # --- the rest, with one virtual edge D -> C ---------------------------
+    rest = np.flatnonzero(owner < 0)
+    redges = [e for e in range(E)
+              if owner[ga.esrc[e]] < 0 and owner[ga.edst[e]] < 0]
+    rg = _subgraph(g, "bundle.rest", rest, redges, [(b.d, b.c, 1)])
+    if rg.n_edges > MAX_EXHAUSTIVE_EDGES:
+        raise SearchDeclined(
+            f"bundle rest has {rg.n_edges} edges, past the enumeration "
+            f"wall {MAX_EXHAUSTIVE_EDGES}")
+    rga = M.graph_arrays(rg)
+    rd, rc = int(np.searchsorted(rest, b.d)), int(np.searchsorted(rest, b.c))
+    v = int(np.flatnonzero((rga.esrc == rd) & (rga.edst == rc))[0])
+    real = np.ones(rg.n_edges, bool)
+    real[v] = False
+    rp = enumerate_valid_edge_cuts(rg)
+    cut_r = (rp & real).astype(np.float64)
+    uncut_r = (~rp & real).astype(np.float64)
+    wr = (cut_r @ rga.inc_src) > 0.0
+    rest_cost = cut_r @ rga.ewords + wr @ rga.feat[:, M.F_OUT]
+    in_sum = uncut_r @ rga.win_dst + rga.feat[None, :, M.F_STATE]
+    pre_bad = ((uncut_r @ rga.inc_src) > 0.0) & (
+        rga.feat[None, :, M.F_OUT_PRE] > budget)
+    in_bad = in_sum > budget
+    in_bad[:, rc] = False  # C's sum takes the branch exits: checked below
+    ok = ~pre_bad.any(axis=1) & ~in_bad.any(axis=1)
+    c_base = in_sum[:, rc]
+    d_pre_ok = rga.feat[rd, M.F_OUT_PRE] <= budget
+    d_out = float(rga.feat[rd, M.F_OUT])
+
+    def times(n, x):
+        return 0.0 if n == 0 else n * x
+
+    def switch(bx, mx):  # cost of moving one branch to a cut D edge
+        return bx - mx if np.isfinite(bx) else np.inf
+
+    # Case I: every branch edge uncut, D and C in one group.
+    cost1 = np.where(ok & ~rp[:, v] & all_in & d_pre_ok
+                     & (c_base + k * wx <= budget), rest_cost, np.inf)
+    # Case II by the number n of branches whose exit is uncut: without a
+    # cut D edge, or with at least one (D's write then paid once).
+    (a0, _), (a1, _) = table[0]
+    (b0, _), (b1, _) = table[1]
+    m0, m1 = min(a0, b0), min(a1, b1)
+    cost2 = np.full((len(rp), k + 1, 2), np.inf)
+    split = ok & rp[:, v]
+    for n in range(k + 1):
+        fit = split & (c_base + n * wx <= budget)
+        plain = times(n, a1) + times(k - n, a0)
+        forced = times(n, m1) + times(k - n, m0) + min(
+            switch(b1, m1) if n else np.inf,
+            switch(b0, m0) if k - n else np.inf)
+        cost2[:, n, 0] = np.where(fit, rest_cost + plain, np.inf)
+        cost2[:, n, 1] = np.where(
+            fit, rest_cost + forced + np.where(wr[:, rd], 0.0, d_out), np.inf)
+    n_scored = int(np.isfinite(cost1).sum() + np.isfinite(cost2).sum())
+    j1 = int(np.argmin(cost1))
+    j2 = np.unravel_index(int(np.argmin(cost2)), cost2.shape)
+    if not (np.isfinite(cost1[j1]) or np.isfinite(cost2[j2])):
+        raise InfeasibleBudgetError(
+            "no feasible grouping under the SRAM budget")
+
+    cuts = np.zeros(E, dtype=bool)
+    if cost1[j1] <= cost2[j2]:
+        row = rp[j1]
+    else:
+        row, (n, forced) = rp[j2[0]], j2[1:]
+        picks = []
+        for i in range(k):
+            xu = int(i < n)
+            if not forced:
+                picks.append(table[0][xu][1])
+            else:
+                a, bb = table[0][xu], table[1][xu]
+                picks.append(a[1] if a[0] <= bb[0] else bb[1])
+        if forced and not any(p[sga.esrc == 0].any() for p in picks):
+            # no branch cut a D edge at its own least cost: force the one
+            # whose switch costs least (cost2 priced it the same way)
+            xu = int(n > 0 and (k == n or switch(b1, m1) <= switch(b0, m0)))
+            i = 0 if xu else n
+            picks[i] = table[1][xu][1]
+        for i, p in enumerate(picks):
+            cuts[bedges[i]] = p
+    cuts[redges] = row[real]
+    return cuts, n_scored
+
+
+def _solve_segment(seg: GraphIR, budget: float) -> tuple[np.ndarray, int]:
+    """(cuts, DP states kept) of one segment: the frontier DP within its
+    caps, else a bundle; a decline or an infeasible budget raises."""
+    try:
+        res = frontier_dp_min_bw(seg, sram_budget_words=budget,
+                                 max_width=FRONTIER_DP_MAX_WIDTH)
+        return res.cuts, res.states
+    except FrontierTooWide as e:
+        bundle = _find_bundle(seg)
+        if bundle is None:
+            raise SearchDeclined(
+                f"{seg.name}: {e}, and no bundle of isomorphic branches "
+                "to solve by counts") from None
+        return _bundle_min_bw(seg, bundle, budget)
+
+
+def segment_dp_min_bw(
+    ir: NetworkIR | GraphIR,
+    *,
+    sram_budget_words: float = float("inf"),
+) -> DPResult:
+    """Exact min-bandwidth grouping by segments (see the section comment
+    above): split ``ir`` at single-node separators, solve each segment (or
+    find it in the memo), and join the segments' cuts.
+
+    The minimum equals :func:`brute_force_min_bw`'s and
+    :func:`frontier_dp_min_bw`'s, bit for bit (the cost is recounted on the
+    joined cuts).  Raises :class:`repro.core.errors.SearchDeclined` when a
+    segment is beyond both solvers and :class:`InfeasibleBudgetError` when
+    a segment has no feasible grouping.  Spans: ``fusion.split`` (``work``
+    = segments) and one ``fusion.segment`` a segment (``work`` = DP states
+    kept, 0 for a memo hit)."""
+    g = as_graph(ir)
+    ga = M.graph_arrays(g)
+    budget = float(sram_budget_words)
+    with spans.span("fusion.split") as sp:
+        seps = single_node_separators(g)
+        bounds = np.searchsorted(ga.esrc, seps)  # edges sorted by src
+        segs = [(a, b, int(lo), int(hi)) for a, b, lo, hi in zip(
+            seps[:-1], seps[1:], bounds[:-1], bounds[1:]) if hi > lo]
+        sp.work = len(segs)
+    cuts = np.zeros(g.n_edges, dtype=bool)
+    kept = 0
+    for a, b, lo, hi in segs:
+        with spans.span("fusion.segment") as sp:
+            key = (ga.feat[a:b + 1].tobytes(), (ga.esrc[lo:hi] - a).tobytes(),
+                   (ga.edst[lo:hi] - a).tobytes(), ga.ewords[lo:hi].tobytes(),
+                   budget)
+            hit = _SEGMENT_MEMO.get(key)
+            if hit is None:
+                seg = _subgraph(g, f"{g.name}[{a}:{b}]", range(a, b + 1),
+                                range(lo, hi))
+                try:
+                    local, n_states = _solve_segment(seg, budget)
+                    local = np.array(local, dtype=bool)
+                    local.setflags(write=False)
+                    hit = (local, n_states)
+                except (SearchDeclined, InfeasibleBudgetError) as e:
+                    hit = ((type(e), e.args), 0)  # no traceback kept alive
+                _SEGMENT_MEMO[key] = hit
+                if len(_SEGMENT_MEMO) > SEGMENT_MEMO_SIZE:
+                    _SEGMENT_MEMO.popitem(last=False)
+                sp.work = hit[1]
+                kept += hit[1]
+            else:
+                _SEGMENT_MEMO.move_to_end(key)
+                sp.work = 0
+        if isinstance(hit[0], tuple):
+            err, args = hit[0]
+            raise err(*args)
+        cuts[lo:hi] = hit[0]
+    labels = cut_group_labels(g, cuts)
+    return DPResult(
+        cuts=cuts,
+        group_cost_words=float(_graph_cost_batch(g, cuts[None, :])[0]),
+        n_groups=int(labels.max()) + 1,
+        engine="segment_dp",
+        states=kept,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1246,22 +1638,36 @@ def optimal_cuts(
     """Grouping search dispatch: chain DP fast path; frontier-state DP for
     general DAGs (exact at any edge count, up to a frontier-width cap —
     ResNet-18's 2^38 space included); when the DAG is too wide for the DP,
-    small graphs keep their certified optimum via exhaustive enumeration
-    and only large-and-wide graphs fall back to beam merge.  The returned
-    :class:`DPResult` carries ``engine`` provenance ("chain_dp" /
-    "frontier_dp" / "exhaustive" / "beam") and ``exact`` so callers can
-    tell a certified optimum from a heuristic answer."""
+    small graphs keep their certified optimum via exhaustive enumeration,
+    large ones get the segment DP (exact, split at single-node separators,
+    bundles of isomorphic branches by counts), and only graphs that it
+    declines fall back to beam merge.  The returned :class:`DPResult`
+    carries ``engine`` provenance ("chain_dp" / "frontier_dp" /
+    "exhaustive" / "segment_dp" / "beam") and ``exact`` so callers can
+    tell a certified optimum from a heuristic answer.
+
+    Runs in a ``fusion.search`` span whose ``work`` is the number of
+    segments the answer was joined from (0 for a whole-graph engine)."""
     g = as_graph(ir)
+    with spans.span("fusion.search") as sp:
+        res = _dispatch(g, float(sram_budget_words), beam_width)
+        sp.work = sum(r.name == "fusion.segment" for r in sp.children)
+    return res
+
+
+def _dispatch(g: GraphIR, budget: float, beam_width: int) -> DPResult:
     if g.is_chain:
-        return optimal_cuts_dp(g, sram_budget_words=sram_budget_words)
-    res = _frontier_dp_cached(g, float(sram_budget_words))
+        return optimal_cuts_dp(g, sram_budget_words=budget)
+    res = _frontier_dp_cached(g, budget)
     if res is not None:
         return dataclasses.replace(res, cuts=res.cuts.copy())
     if (
         g.n_edges <= MAX_EXHAUSTIVE_EDGES
         and len(g.nodes) <= MAX_EXHAUSTIVE_LAYERS
     ):
-        return brute_force_min_bw(g, sram_budget_words=sram_budget_words)
-    return beam_merge_cuts(
-        g, beam_width=beam_width, sram_budget_words=sram_budget_words
-    )
+        return brute_force_min_bw(g, sram_budget_words=budget)
+    try:
+        return segment_dp_min_bw(g, sram_budget_words=budget)
+    except SearchDeclined:
+        return beam_merge_cuts(g, beam_width=beam_width,
+                               sram_budget_words=budget)

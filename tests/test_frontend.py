@@ -329,3 +329,95 @@ def test_traced_mobilenet_runs_paper_flow_end_to_end():
     assert res.n_pruned >= 0 and res.n_feasible >= 1
     cmp_lbl = M.bandwidth_ref(g, fusion.layer_by_layer_cuts(g))
     assert res.best_metrics.bandwidth_words < cmp_lbl
+
+
+# ---------------------------------------------------------------------------
+# Dead dataflow: nodes from which no traced output can be reached
+# ---------------------------------------------------------------------------
+
+
+def _reaches_output(g) -> bool:
+    """Every node has a path to a sink, and every sink is a traced output
+    (the builders below return one tensor, so one sink)."""
+    sinks = [i for i in range(g.n_nodes) if g.sink_mask[i]]
+    return len(sinks) == 1 and sinks[0] == g.n_nodes - 1
+
+
+def test_trace_leaves_out_dead_dataflow():
+    """A loss term the function computes and drops (two matmuls and the
+    join of their sums) adds no node; the live nodes are renumbered."""
+    import jax
+    import jax.numpy as jnp
+
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    params = {k: sds(64, 64) for k in ("w1", "w2", "w3", "w4")}
+
+    def fn(p, x):
+        h = x @ p["w1"]
+        aux = jnp.sum(h @ p["w2"]) + jnp.sum(x @ p["w4"])  # dropped
+        del aux
+        return h @ p["w3"]
+
+    g = F.trace(fn, params, sds(16, 64), name="dead")
+    assert [n.name for n in g.nodes] == ["matmul0", "matmul1"]
+    assert [(e.src, e.dst) for e in g.edges] == [(0, 1)]
+
+
+def test_trace_with_only_dead_layers_is_refused():
+    import jax
+    import jax.numpy as jnp
+
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+
+    def fn(p, x):
+        del p
+        jnp.sum(x @ jnp.ones((64, 8)))
+        return x
+
+    with pytest.raises(ValueError, match="no layers traced"):
+        F.trace(fn, {}, sds(16, 64), name="none")
+
+
+def test_every_traced_node_reaches_an_output():
+    from repro.configs import REGISTRY
+
+    jamba = F.transformer_graph(REGISTRY["jamba2-mini"], seq_len=4096)
+    mixtral = F.transformer_graph(REGISTRY["mixtral-8x7b"], seq_len=512)
+    for g in (F.resnet18_graph(), F.mobilenet_graph(), mixtral, jamba):
+        assert _reaches_output(g), g.name
+    # the builders' graphs are the ones they were before dead dataflow was
+    # left out: Mixtral's period has one router and so no dropped join
+    assert (mixtral.n_nodes, mixtral.n_edges) == (43, 60)
+    assert (jamba.n_nodes, jamba.n_edges) == (369, 563)
+    assert sorted({n.kind for n in jamba.nodes}) == [
+        "actmul", "elementwise", "matmul", "scan"]
+    assert sum(n.kind == "scan" for n in jamba.nodes) == 7
+    assert all(n.state_words == 8192 * 16 for n in jamba.nodes
+               if n.kind == "scan")
+
+
+@pytest.mark.parametrize("batch_groups", [False, True])
+def test_batch_groups_prices_one_product_per_head(batch_groups):
+    """Q K^T over H heads: the batch axis folds into contraction and output
+    either way (same words, same edges); as groups its MACs are those of H
+    per-head products, folded H times that (the default)."""
+    import jax
+    import jax.numpy as jnp
+
+    S, H, hd = 16, 4, 8
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    params = {"wq": sds(H * hd, H * hd), "wk": sds(H * hd, H * hd)}
+
+    def fn(p, x):
+        q = (x @ p["wq"]).reshape(S, H, hd)
+        k = (x @ p["wk"]).reshape(S, H, hd)
+        return jnp.einsum("qhd,khd->hqk", q, k)
+
+    g = F.trace(fn, params, sds(S, H * hd), name="qk",
+                batch_groups=batch_groups)
+    (a,) = [n for n in g.nodes if n.kind == "actmul"]
+    assert (a.n_in, a.n_out, a.h_in, a.w_in) == (H * hd, H * S, S, 1)
+    assert a.groups == (H if batch_groups else 1)
+    assert a.macs == H * S * S * hd * (1 if batch_groups else H)
+    assert a.out_words == H * S * S
+    assert [e.words for e in g.edges] == [S * H * hd, S * H * hd]

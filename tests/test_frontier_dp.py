@@ -144,6 +144,22 @@ def _wide_dag(n_mid: int) -> GraphIR:
     return GraphIR("wide", tuple(nodes), tuple(edges))
 
 
+def _dense_wide_dag(rng, n_mid: int) -> GraphIR:
+    """source -> n_mid middles of distinct sizes -> sink join, with random
+    edges among the middles: width n_mid, no separator, no two isomorphic
+    branches."""
+    nodes = [LayerSpec("src", "conv", 4, 4, 8, 8, 3, 3, 1)]
+    for i in range(n_mid):
+        nodes.append(LayerSpec(f"m{i}", "conv", 4, 4 + i, 8, 8, 3, 3, 1))
+    nodes.append(LayerSpec("join", "elementwise", 4, 4, 8, 8))
+    edges = [EdgeSpec(0, i + 1, 256) for i in range(n_mid)]
+    edges += [EdgeSpec(i + 1, n_mid + 1, nodes[i + 1].out_words)
+              for i in range(n_mid)]
+    edges += [EdgeSpec(i + 1, j + 1, 64) for i in range(n_mid)
+              for j in range(i + 1, n_mid) if rng.random() < 0.3]
+    return GraphIR("dense", tuple(nodes), tuple(edges))
+
+
 def _wide_fanin_dag(n_src: int) -> GraphIR:
     """n_src parallel sources feeding one join: width n_src, n_src edges —
     wide for the DP but small enough to enumerate."""
@@ -164,13 +180,26 @@ def test_dispatch_engines():
     assert fanin.n_edges <= fusion.MAX_EXHAUSTIVE_EDGES
     res = fusion.optimal_cuts(fanin)
     assert res.engine == "exhaustive" and res.exact
-    # wide AND beyond the enumeration wall: beam, with provenance saying so
+    # wide AND beyond the enumeration wall, but its middles are isomorphic
+    # branches between one source and one join: the segment DP certifies
+    # the optimum (at no budget, one fused group)
     wide = _wide_dag(fusion.FRONTIER_DP_MAX_WIDTH + 1)
     assert wide.n_edges > fusion.MAX_EXHAUSTIVE_EDGES
     res = fusion.optimal_cuts(wide)
-    assert res.engine == "beam" and not res.exact
+    assert res.engine == "segment_dp" and res.exact
+    assert res.group_cost_words == fusion._graph_cost(
+        wide, np.zeros(wide.n_edges, bool))
     with pytest.raises(fusion.FrontierTooWide):
         fusion.frontier_dp_min_bw(wide)
+    # wide, beyond the wall, and dense with distinct middles: no separator
+    # and no bundle, so beam, with provenance saying so
+    dense = _dense_wide_dag(np.random.default_rng(7),
+                            fusion.FRONTIER_DP_MAX_WIDTH + 1)
+    assert dense.n_edges > fusion.MAX_EXHAUSTIVE_EDGES
+    res = fusion.optimal_cuts(dense)
+    assert res.engine == "beam" and not res.exact
+    with pytest.raises(fusion.SearchDeclined):
+        fusion.segment_dp_min_bw(dense)
 
 
 def test_state_cap_raises_frontier_too_wide():
