@@ -907,17 +907,21 @@ def run_fleet(
     a device sequence is used as given.  H is padded to a device-count
     multiple with copies of config 0 — inert rows sliced off before
     metrics composition, the PR 4 padding idiom on the hardware axis — and
-    each device evaluates its H-shard locally; the (G, H, C, 5) raw plane
-    comes back in one cross-device gather and the per-graph argmin/Pareto
-    run on the host over the whole plane, so sharded results are
-    **bit-identical** at any device count (asserted at 1/2/8 host devices
-    in tests/test_multidevice.py).  The executable cache keys on the mesh
-    fingerprint, so per-layout programs never collide
-    (``sweep_cache_stats()["entries"]``).
+    each device evaluates its H-shard locally.  Where the sweep prunes
+    (below), each device also classes its shard and only one summary of
+    the whole plane crosses the mesh
+    (:func:`repro.core.metrics.sharded_pruned_fleet_kernel`); otherwise
+    the (G, H, C, 5) raw plane comes back in one cross-device gather and
+    the per-graph argmin/Pareto run on the host over the whole plane.
+    Either way sharded results are **bit-identical** at any device count
+    (asserted at 1/2/3/4/8 host devices in tests/test_multidevice.py).
+    The executable cache keys on the mesh fingerprint, so per-layout
+    programs never collide (``sweep_cache_stats()["entries"]``).
 
-    Where only each graph's pick is asked for — ``pareto=False``, no
-    ``hw_chunk`` and ``devices=None`` — the sweep program also prunes the
-    plane on the device (:func:`repro.core.metrics.
+    Where only each graph's pick is asked for — ``pareto=False`` and no
+    ``hw_chunk``, on one device or a mesh — and the float32 bound holds
+    (:func:`repro.core.metrics.prune_applies`), the sweep program also
+    prunes the plane on the device (:func:`repro.core.metrics.
     _evaluate_fleet_graph_pruned`) and only its summary is fetched: the
     counts, and at most :data:`~repro.core.metrics.PRUNE_ROWS` raw rows a
     graph that can still win.  The host composes their energy in float64
@@ -1097,16 +1101,17 @@ def run_fleet(
         # valid arithmetic, sliced off below before metrics composition).
         mesh_key = _SINGLE_MESH_KEY
         hw_swept = hw_rows
-        # Prune on the device where only the pick is asked for, from one
-        # single-device program: the Pareto front needs every feasible row,
-        # and chunked and sharded sweeps keep their raw planes.
-        prune = (not pareto and hw_chunk is None and devices is None
+        # Prune on the device where only the pick is asked for: the Pareto
+        # front needs every feasible row, and chunked sweeps keep their
+        # raw planes.
+        prune = (not pareto and hw_chunk is None
                  and M.prune_applies(hw_rows, area_consts))
         if devices is None:
             kernel = M._jit_fleet_graph_pruned if prune else M._jit_fleet_graph
         else:
             mesh = hardware_mesh(devices)
-            kernel = M.sharded_fleet_kernel(mesh)
+            kernel = (M.sharded_pruned_fleet_kernel(mesh) if prune
+                      else M.sharded_fleet_kernel(mesh))
             mesh_key = mesh_fingerprint(mesh)
             D = int(mesh.devices.size)
             H_padded = -(-H // D) * D
@@ -1131,6 +1136,8 @@ def run_fleet(
         if prune:
             args += (np.asarray(counts, np.int32),
                      *M.prune_limits(constraints.as_row()))
+            if devices is not None:
+                args += (np.int32(H),)
         # f64-exactness guard on the giant-config feature tables (llama4 /
         # arctic edge words reach ~1e10 — far below 2^53, but a corrupted or
         # overflowed table must fail loudly before the sweep, not split ulps
@@ -1190,10 +1197,16 @@ def run_fleet(
             # The mesh is sick (compile/execute kept failing through the
             # retry budget): degrade to the ladder's single-device rung.
             # The sharded kernel is row-parallel with no cross-row
-            # reduction, so the salvaged result is bit-identical to the
-            # mesh sweep — the fallback trades throughput, never answers.
+            # reduction, and the sharded pruned program's summary equals
+            # the single-device one, so the salvaged result is
+            # bit-identical to the mesh sweep — the fallback trades
+            # throughput, never answers.
             mesh_degraded = True
-            kernel, mesh_key = M._jit_fleet_graph, _SINGLE_MESH_KEY
+            mesh_key = _SINGLE_MESH_KEY
+            if prune:  # the single-device program takes no n_hw
+                kernel, args = M._jit_fleet_graph_pruned, args[:-1]
+            else:
+                kernel = M._jit_fleet_graph
             args = args[:7] + (hw_rows,) + args[8:]
             raw, compile_seconds, sweep_seconds = _compute(
                 0, args, kernel, mesh_key, 0, 1

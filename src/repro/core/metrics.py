@@ -811,19 +811,20 @@ def _first_k(mask: jnp.ndarray, k: int) -> jnp.ndarray:
                             side="left")
 
 
-def _prune_one_graph(raw, e3, n_cuts, lim_lo, lim_hi):
-    """One graph's summary of its raw (H, C, 5) plane; see
-    :func:`_evaluate_fleet_graph_pruned`."""
+def _maybe_poisoned(raw):
+    """(...,) whether each raw row (..., 5) is a cell the host's finite
+    guard could flag: see :data:`_POISON_FLOOR`."""
+    bad = (~jnp.isfinite(raw)) | (raw < 0.0) | (raw > _POISON_FLOOR)
+    return jnp.any(bad, axis=-1)
+
+
+def _take_survivors(raw, survive):
+    """(h, c, rows, per_row) of the first :data:`PRUNE_ROWS` survivors of
+    one graph's raw (H, C, 5) plane in (h, c) order, and the survivors of
+    each hardware row.  Past the last survivor, entries repeat the cell
+    (H - 1, C - 1)."""
     H, C, _ = raw.shape
     K = PRUNE_ROWS
-    real = (jnp.arange(C) < n_cuts)[None, :]
-    bad = (~jnp.isfinite(raw)) | (raw < 0.0) | (raw > _POISON_FLOOR)
-    n_poison = jnp.sum(jnp.any(bad, axis=-1) & real)
-    key, sure, over = _prune_classes(raw, e3[:, None, :], lim_lo, lim_hi)
-    sure &= real
-    undecided = real & ~sure & ~over
-    least = jnp.min(jnp.where(sure, key, jnp.inf))
-    survive = undecided | (sure & (key <= least * PRUNE_RATIO))
     # Survivors in (h, c) order: first the hardware rows that hold any,
     # then the first K survivors among those rows.
     per_row = jnp.sum(survive, axis=1)
@@ -832,7 +833,21 @@ def _prune_one_graph(raw, e3, n_cuts, lim_lo, lim_hi):
     sub = survive[rows_c] & (rows < H)[:, None]
     flat = jnp.minimum(_first_k(sub, K), K * C - 1)
     h, c = rows_c[flat // C], flat % C
-    got = raw[h, c]
+    return h, c, raw[h, c], per_row
+
+
+def _prune_one_graph(raw, e3, n_cuts, lim_lo, lim_hi):
+    """One graph's summary of its raw (H, C, 5) plane; see
+    :func:`_evaluate_fleet_graph_pruned`."""
+    C = raw.shape[1]
+    real = (jnp.arange(C) < n_cuts)[None, :]
+    n_poison = jnp.sum(_maybe_poisoned(raw) & real)
+    key, sure, over = _prune_classes(raw, e3[:, None, :], lim_lo, lim_hi)
+    sure &= real
+    undecided = real & ~sure & ~over
+    least = jnp.min(jnp.where(sure, key, jnp.inf))
+    survive = undecided | (sure & (key <= least * PRUNE_RATIO))
+    h, c, got, per_row = _take_survivors(raw, survive)
     _, got_sure, _ = _prune_classes(got, e3[h], lim_lo, lim_hi)
     return PlaneSummary(
         n_poison=n_poison,
@@ -887,11 +902,97 @@ def _evaluate_fleet_graph_pruned(
 _jit_fleet_graph_pruned = jax.jit(_evaluate_fleet_graph_pruned)
 
 
-# Per-mesh jitted shard_map wrappers around the fleet kernel.  Meshes are
-# few (one per device layout the process ever sweeps on), so an unbounded
-# memo is fine; the AOT executable cache in repro.core.flow is what bounds
-# compiled-program memory.
+def _evaluate_fleet_graph_pruned_shard(
+    feat, esrc, edst, ewords, src_mask, sink_mask, cuts_batch, hw_rows,
+    area_consts, node_mask, edge_mask, n_cuts, lim_lo, lim_hi,
+    n_hw: jnp.ndarray,  # () int32 — real hardware points, before padding
+):
+    """One device's part of :func:`sharded_pruned_fleet_kernel`: the raw
+    plane of its H-shard and the summary of the whole plane, the same
+    :class:`PlaneSummary` that :func:`_evaluate_fleet_graph_pruned` gives
+    for the unpadded hardware axis."""
+    from ..parallel.sharding import HW_AXIS
+
+    raw = _evaluate_fleet_graph(
+        feat, esrc, edst, ewords, src_mask, sink_mask, cuts_batch, hw_rows,
+        area_consts, node_mask, edge_mask,
+    )
+    G, Hl, C, _ = raw.shape
+    K = PRUNE_ROWS
+    e3 = hw_rows[:, H_EDRAM:H_EPB + 1]
+    h0 = jax.lax.axis_index(HW_AXIS) * Hl
+    # Real cells: a cut row below n_cuts on a hardware row below n_hw (the
+    # padded rows repeat config 0 and would be counted twice).
+    real = ((jnp.arange(C)[None, :] < n_cuts[:, None])[:, None, :]
+            & (h0 + jnp.arange(Hl) < n_hw)[None, :, None])
+    n_poison = jnp.sum(_maybe_poisoned(raw) & real, axis=(1, 2),
+                       dtype=jnp.int32)
+    key, sure, over = _prune_classes(raw, e3[None, :, None, :], lim_lo,
+                                     lim_hi)
+    sure &= real
+    undecided = real & ~sure & ~over
+    least = jax.lax.pmin(jnp.min(jnp.where(sure, key, jnp.inf), axis=(1, 2)),
+                         HW_AXIS)
+    survive = undecided | (sure & (key <= least[:, None, None] * PRUNE_RATIO))
+    h, c, got, per_row = jax.vmap(_take_survivors)(raw, survive)
+    _, got_sure, _ = _prune_classes(got, e3[h], lim_lo, lim_hi)
+    n_local = jnp.sum(per_row, axis=1, dtype=jnp.int32)
+    counts = jax.lax.psum(jnp.stack([
+        n_poison, jnp.sum(sure, axis=(1, 2), dtype=jnp.int32),
+        jnp.sum(undecided, axis=(1, 2), dtype=jnp.int32), n_local]), HW_AXIS)
+    # Where fewer than K cells survive, the one-device summary pads with
+    # the last real cell (n_hw - 1, C - 1): the shard that holds it sends
+    # it beside its survivors.
+    last = jnp.clip(n_hw - 1 - h0, 0, Hl - 1)
+    tail = raw[:, last, C - 1]
+    _, tail_sure, _ = _prune_classes(tail, e3[last], lim_lo, lim_hi)
+    ints = jnp.stack([
+        (h + h0).astype(jnp.int32), c.astype(jnp.int32),
+        got_sure.astype(jnp.int32),
+        (jnp.arange(K) < n_local[:, None]).astype(jnp.int32)], axis=-1)
+    ints = jax.lax.all_gather(ints, HW_AXIS)  # (D, G, K, 4)
+    rows = jax.lax.all_gather(got, HW_AXIS)  # (D, G, K, 5)
+    tails = jax.lax.all_gather(tail, HW_AXIS)  # (D, G, 5)
+    tail_sures = jax.lax.all_gather(tail_sure, HW_AXIS)  # (D, G)
+    D = ints.shape[0]
+    owner = (n_hw - 1) // Hl
+
+    def merge(ints, rows, tail, tail_sure):
+        # Shards hold contiguous H ranges, so their survivors in shard
+        # order are the global (h, c) order, and the global first K are
+        # among the shards' first K.
+        pos = _first_k(ints[..., 3] > 0, K)
+        pad = pos >= D * K
+        at = jnp.minimum(pos, D * K - 1)
+        ints = ints.reshape(D * K, 4)[at]
+        return (
+            jnp.where(pad, n_hw - 1, ints[:, 0]),
+            jnp.where(pad, C - 1, ints[:, 1]),
+            jnp.where(pad, tail_sure[owner], ints[:, 2] > 0),
+            jnp.where(pad[:, None], tail[owner], rows.reshape(D * K, 5)[at]),
+        )
+
+    h, c, got_sure, got = jax.vmap(merge, in_axes=(1, 1, 1, 1))(
+        ints, rows, tails, tail_sures)
+    n_poison, n_sure, n_undecided, n_survivors = counts.astype(int)
+    return raw, PlaneSummary(
+        n_poison=n_poison,
+        n_sure=n_sure,
+        n_undecided=n_undecided,
+        n_survivors=n_survivors,
+        h=h,
+        c=c,
+        sure=got_sure,
+        rows=got,
+    )
+
+
+# Per-mesh jitted shard_map wrappers around the fleet programs.  Meshes are
+# few (one per device layout the process ever sweeps on), so unbounded
+# memos are fine; the AOT executable cache in repro.core.flow is what
+# bounds compiled-program memory.
 _SHARDED_FLEET_KERNELS: dict = {}
+_SHARDED_PRUNED_KERNELS: dict = {}
 
 
 def sharded_fleet_kernel(mesh):
@@ -905,7 +1006,10 @@ def sharded_fleet_kernel(mesh):
     identical to the single-device program (rows are vmapped independently;
     no cross-row reduction exists to reassociate), which is why the sharded
     sweep is bit-identical, not just close (asserted in
-    tests/test_multidevice.py at 2 and 8 host devices).
+    tests/test_multidevice.py at 2 and 8 host devices).  This is the
+    whole-plane program: :func:`repro.core.flow.run_fleet` takes it for a
+    Pareto front and where pruning does not apply, and
+    :func:`sharded_pruned_fleet_kernel` otherwise.
 
     Callers must pad H to a multiple of the device count first
     (:func:`repro.core.flow.run_fleet` pads with copies of row 0 and slices
@@ -929,6 +1033,54 @@ def sharded_fleet_kernel(mesh):
             )
         )
         _SHARDED_FLEET_KERNELS[key] = fn
+    return fn
+
+
+def sharded_pruned_fleet_kernel(mesh):
+    """:func:`_evaluate_fleet_graph_pruned` shard_mapped over ``mesh``'s
+    1-D hardware axis -> (raw plane, summary).
+
+    It takes the pruned program's arguments, with ``hw_rows`` sharded
+    ``P(axis)`` and padded to a multiple of the device count as for
+    :func:`sharded_fleet_kernel`, and one more: ``n_hw``, the number of
+    real hardware rows.  The raw plane stays on the devices laid out
+    ``P(None, axis)``; the :class:`PlaneSummary` comes back replicated and
+    equals the one-device program's summary of the unpadded plane bit for
+    bit, overflow and the rows past the survivors included.
+
+    Each device classes the real cells of its H-shard (global hardware
+    index below ``n_hw``, so the padded copies of config 0 count nowhere)
+    and the counts are summed across the mesh.  The least surely-feasible
+    key is the least across the mesh (``pmin``), one float32 a graph:
+    survival then means what it means on one device.  A shard-local least
+    would not do: a shard with no surely-feasible cell would keep every
+    undecided and surely-feasible cell it has and overflow the summary.
+    Each device then takes its first :data:`PRUNE_ROWS` survivors in
+    (h, c) order and the summaries are all-gathered; shards hold
+    contiguous H ranges, so the first ``PRUNE_ROWS`` of the concatenation
+    in shard order are the global first ones.  The float64 rows cross the
+    mesh as they are, a few KB a graph, and the plane is never gathered.
+    """
+    from ..parallel.sharding import HW_AXIS, mesh_fingerprint
+
+    key = mesh_fingerprint(mesh)
+    fn = _SHARDED_PRUNED_KERNELS.get(key)
+    if fn is None:
+        from jax.sharding import PartitionSpec as P
+
+        repl = P()
+        fn = jax.jit(
+            jax.shard_map(
+                _evaluate_fleet_graph_pruned_shard,
+                mesh=mesh,
+                in_specs=(repl,) * 7 + (P(HW_AXIS),) + (repl,) * 7,
+                out_specs=(P(None, HW_AXIS), repl),
+                # the summary is the same on every device by construction:
+                # all-gathered rows and psum/pmin results, merged alike
+                check_vma=False,
+            )
+        )
+        _SHARDED_PRUNED_KERNELS[key] = fn
     return fn
 
 

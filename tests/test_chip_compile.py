@@ -105,19 +105,27 @@ def test_fleet_kernel_compiles_for_one_chip(one_chip, fleet_args):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
-def test_pruned_fleet_program_compiles_for_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def exhaustive_args():
+    """The pruned program's arguments at the exhaustive co-search's shape:
+    VGG-16, one 4096-grouping slice, the 2560-point grid."""
     g = as_graph(vgg16_ir(pool_mode="separate"))
     pg = pad_graph(g, n_nodes=flow.NODE_BUCKET_FLOOR,
                    n_edges=flow.EDGE_BUCKET_FLOOR)
     grid = config_space_grid()
-    G, H, C = 1, len(grid), 4096
+    C = 4096
     args = tuple(np.asarray(a)[None] for a in (
         pg.feat, pg.esrc, pg.edst, pg.ewords, pg.src_mask, pg.sink_mask,
         np.zeros((C, pg.n_edges_padded), bool)))
-    args += (np.stack([c.as_row() for c in grid]),
-             M.area_consts_of_space(grid), pg.node_mask[None],
-             pg.edge_mask[None], np.array([C], np.int32),
-             *M.prune_limits(Constraints().as_row()))
+    return args + (np.stack([c.as_row() for c in grid]),
+                   M.area_consts_of_space(grid), pg.node_mask[None],
+                   pg.edge_mask[None], np.array([C], np.int32),
+                   *M.prune_limits(Constraints().as_row()))
+
+
+def test_pruned_fleet_program_compiles_for_one_chip(one_chip, exhaustive_args):
+    args = exhaustive_args
+    (G, C, _), H = args[6].shape, args[7].shape[0]
     with jax.enable_x64(True):
         specs = _specs(args, [one_chip] * len(args))
         compiled = M._jit_fleet_graph_pruned.lower(*specs).compile()
@@ -127,6 +135,27 @@ def test_pruned_fleet_program_compiles_for_one_chip(one_chip):
     # stay small beside the plane
     assert plane < mem.output_size_in_bytes < plane + 2**16
     assert mem.temp_size_in_bytes < 1.1 * plane
+
+
+def test_sharded_pruned_program_compiles_for_four_chips(topo, exhaustive_args):
+    mesh = Mesh(np.asarray(topo.devices), (HW_AXIS,))
+    args = exhaustive_args + (np.int32(exhaustive_args[7].shape[0]),)
+    (G, C, _), H = args[6].shape, args[7].shape[0]
+    shardings = [NamedSharding(mesh, P())] * len(args)
+    shardings[7] = NamedSharding(mesh, P(HW_AXIS))
+    with jax.enable_x64(True):
+        specs = _specs(args, shardings)
+        compiled = M.sharded_pruned_fleet_kernel(mesh).lower(*specs).compile()
+    mem = compiled.memory_analysis()
+    shard = G * H // 4 * C * 5 * 8
+    # each device keeps its quarter of the plane and a summary of a few KB;
+    # only the summaries cross the mesh, never the plane
+    assert shard < mem.output_size_in_bytes < shard + 2**16
+    assert mem.temp_size_in_bytes < 1.1 * shard
+    text = compiled.as_text()
+    assert "all-gather" in text
+    assert not any("all-gather" in line and "[4,1,2560" in line
+                   for line in text.splitlines())
 
 
 def test_sharded_fleet_kernel_compiles_for_four_chips(topo, fleet_args):

@@ -286,12 +286,27 @@ def test_each_other_path_reads_every_row():
     base = _outcome(lambda: flow.run_fleet(
         g, config_space=GRID, constraints=loose, groupings=cuts))
     assert _select_work() <= K
-    for kw in ({"pareto": True}, {"hw_chunk": 16}, {"devices": 1}):
+    for kw in ({"pareto": True}, {"hw_chunk": 16}):
         fl = flow.run_fleet(g, config_space=GRID, constraints=loose,
                             groupings=cuts, **kw)
         assert _select_work() == H * 4, kw
         fields = _outcome(lambda: fl)
         assert fields == base, kw
+
+
+def test_a_mesh_prunes_too():
+    """A sharded sweep prunes on the mesh: its select reads the survivors
+    only, and its pick is the single-device pick."""
+    g = _graphs()
+    cuts = _batches(g, [4])
+    loose = Constraints(*[INF] * 4)
+    base = _outcome(lambda: flow.run_fleet(
+        g, config_space=GRID, constraints=loose, groupings=cuts))
+    fl = flow.run_fleet(g, config_space=GRID, constraints=loose,
+                        groupings=cuts, devices=1)
+    assert fl.device_count == 1
+    assert _select_work() <= K
+    assert _outcome(lambda: fl) == base
 
 
 def test_hook_reads_the_device_plane_bit_for_bit():

@@ -246,6 +246,167 @@ def test_sharded_fleet_search_groupings_and_budget_8dev():
     assert "sharded search ok 8" in out
 
 
+# Spies on run_fleet's sweeps in a subprocess: the summary each pruned
+# sweep fetched, and the FlowResult fields a sweep answers with.
+_SUMMARY_SPY = """
+from repro.core import flow, spans
+from repro.core import metrics as M
+
+summaries = []
+_run_sweep = flow._run_sweep
+
+
+def spy(exe, args):
+    out, dt = _run_sweep(exe, args)
+    if isinstance(out, flow.DevicePlane):
+        summaries.append(out.summary)
+    return out, dt
+
+
+flow._run_sweep = spy
+
+
+def same_summary(a, b):
+    for f in M.PlaneSummary._fields:
+        x, y = getattr(a, f), getattr(b, f)
+        if f == "rows":
+            x, y = x.view(np.uint64), y.view(np.uint64)
+        assert x.dtype == y.dtype and np.array_equal(x, y), (f, x, y)
+
+
+def fields(fl):
+    return [(r.best_hw, r.best_cuts.tolist(), r.best_metrics, r.n_feasible,
+             r.group_sizes, r.n_candidates, r.n_pruned, r.search_engine,
+             repr(r.quarantine)) for r in fl.results] + [
+        fl.n_candidates, repr(fl.quarantine)]
+
+
+def select_work():
+    return spans.per_call("fleet.call", "fleet.select")[-1][1]
+"""
+
+
+def test_sharded_pruned_summary_equals_single_device():
+    # H=37 is a multiple of none of 2, 3 and 4: each mesh pads H with
+    # copies of config 0, which the summary must not count.
+    out = run_py(_SUMMARY_SPY + textwrap.dedent("""
+    from repro.core.arch import Constraints, config_space_grid
+    from repro.core.ir import as_graph, residual_block_ir, vgg16_ir
+
+    space = config_space_grid(
+        f1s=(2, 4), f2s=(2, 4), f3s=(2, 4), f4s=(2, 4),
+        bus_widths=(2, 4), sram_splits=("unified",),
+    )[:37]
+    irs = [vgg16_ir(pool_mode="separate"), residual_block_ir()]
+    rng = np.random.default_rng(7)
+    batches = [np.unique(rng.random((300, as_graph(g).n_edges)) < 0.5, axis=0)
+               for g in irs]
+    inf = float("inf")
+    # the paper's limits, and a tight set some cells are surely over
+    for limits in (Constraints(), Constraints(inf, 5e7, 1e8, 1.2e7)):
+        base = flow.run_fleet(irs, config_space=space, constraints=limits,
+                              groupings=batches)
+        one = summaries[-1]
+        assert one.decides()
+        assert (one.n_sure + one.n_undecided
+                < len(space) * np.array([len(b) for b in batches])).any()
+        for d in (2, 3, 4):
+            fl = flow.run_fleet(irs, config_space=space, constraints=limits,
+                                groupings=batches, devices=d)
+            assert fl.device_count == d
+            same_summary(summaries[-1], one)
+            assert fields(fl) == fields(base)
+            assert select_work() <= M.PRUNE_ROWS * len(irs)
+            print("devices", d, "ok")
+    """), n_devices=4)
+    assert out.count("ok") == 6
+
+
+def test_sharded_pruned_fallbacks_equal_the_whole_plane():
+    # The crafted planes of tests/test_fleet_reduce.py, each shard's part
+    # put in the fleet kernel's place, swept on a 4-device mesh (12
+    # hardware rows a shard): the pruned pick, the summary and the
+    # fallbacks (overflow across shards, no surely-feasible cell, poison
+    # in shards 1 and 2) all equal the single-device program's, and each
+    # pick equals the whole-plane path's, quarantine provenance included.
+    out = run_py(_SUMMARY_SPY + textwrap.dedent("""
+    import sys
+    from jax import lax
+    from repro.core.arch import Constraints
+    from repro.core.errors import RetryPolicy
+    from repro.testing.faults import FaultInjector
+
+    sys.path.insert(0, "tests")
+    import test_fleet_reduce as R
+
+    kernel = M._evaluate_fleet_graph
+
+    def use(fn):
+        # put fn in the fleet kernel's place, to be traced anew
+        M._evaluate_fleet_graph = fn
+        M._jit_fleet_graph_pruned = jax.jit(
+            lambda *a: M._evaluate_fleet_graph_pruned(*a))
+        M._SHARDED_PRUNED_KERNELS.clear()
+        flow.clear_sweep_cache()
+
+    def put(plane):
+        def crafted(*args):
+            n = args[7].shape[0]  # this program's hardware rows
+            if n == plane.shape[1]:
+                return jnp.asarray(plane)
+            start = lax.axis_index("hardware") * n
+            return lax.dynamic_slice_in_dim(jnp.asarray(plane), start, n,
+                                            axis=1)
+
+        use(crafted)
+
+    def outcome(graphs, batches, limits, devices, hooks=None):
+        return R._outcome(lambda: flow.run_fleet(
+            graphs, config_space=R.GRID, constraints=limits,
+            groupings=batches, devices=devices, hooks=hooks))
+
+    for case in sorted(R.CASES):
+        rng = np.random.default_rng([0, len(case)])
+        plane, counts, limits = R.CASES[case](rng)
+        put(plane)
+        graphs = R._graphs(len(counts))
+        batches = R._batches(graphs, counts)
+        single = outcome(graphs, batches, limits, None)
+        one = summaries[-1]
+        sharded = outcome(graphs, batches, limits, 4)
+        same_summary(summaries[-1], one)
+        work = select_work()
+        assert sharded == single, case
+        assert outcome(graphs, batches, limits, 4, R.Full()) == single, case
+        if case in R.FALLS_BACK:
+            assert work == R.H * sum(counts), case
+        else:
+            assert work <= R.K * len(counts), case
+        if case == "poisoned":
+            assert sharded[-1].count("QuarantinedCell(") == 4
+        print(case, "ok")
+
+    # a sick mesh degrades a pruned sweep to the single-device program
+    use(kernel)
+    graphs = R._graphs()
+    batches = R._batches(graphs, [16])
+    single = flow.run_fleet(graphs, config_space=R.GRID,
+                            constraints=Constraints(), groupings=batches)
+    faults = FaultInjector(mesh_fail_sweeps=2)
+    fl = flow.run_fleet(graphs, config_space=R.GRID,
+                        constraints=Constraints(), groupings=batches,
+                        devices=4, hooks=faults,
+                        retry_policy=RetryPolicy(max_retries=1,
+                                                 backoff_seconds=0.0))
+    assert fl.mesh_degraded and fl.device_count == 1
+    assert faults.counts["injected_mesh_failures"] == 2
+    assert select_work() <= R.K
+    assert fields(fl) == fields(single)
+    print("degraded ok")
+    """), n_devices=4)
+    assert out.count(" ok") == 15
+
+
 def test_compressed_psum_accuracy_and_wire_dtype():
     out = run_py("""
     from functools import partial
