@@ -1,11 +1,11 @@
-"""Machine metadata stamped into every ``BENCH_*.json`` record.
+"""Machine metadata stamped into the ``BENCH_zoo.json`` record.
 
-Throughput and speedup numbers are meaningless without knowing what they
-ran on — in particular the sharded-sweep scaling in ``BENCH_shard.json``
-is bounded by *physical* cores, not by ``jax.device_count()`` (the
-``--xla_force_host_platform_device_count`` flag happily splits one core
-into eight "devices").  Each writer calls :func:`machine_metadata` once
-and embeds the result under a ``"machine"`` key.
+Timings are meaningless without knowing what they ran on, and a host's
+throughput is bounded by its *physical* cores, not by
+``jax.device_count()`` (the ``--xla_force_host_platform_device_count``
+flag happily splits one core into eight "devices").  The writer calls
+:func:`machine_metadata` once and embeds the result under a ``"machine"``
+key.
 """
 from __future__ import annotations
 

@@ -101,23 +101,6 @@ def table3_arch_compare():
                  f"A={m.area_um2/1e6:.1f}mm2;BW={m.bandwidth_words/1e6:.1f}MB")
 
 
-def table4_sweep_throughput():
-    """Vectorised flow throughput: the exhaustive sweep as one XLA program."""
-    print("\n== table4: evaluator sweep throughput ==")
-    ir = vgg16_ir(pool_mode="separate")
-    res, us = timed(
-        run_flow, ir, constraints=PAPER_CONSTRAINTS, groupings="exhaustive",
-        reps=1,
-    )
-    emit("table4.exhaustive_sweep", us,
-         f"{res.n_candidates}cand;{res.candidates_per_second:,.0f}cand_per_s")
-    res2, us2 = timed(
-        run_flow, ir, constraints=PAPER_CONSTRAINTS, groupings="pool", reps=3,
-    )
-    emit("table4.predefined_sweep", us2,
-         f"{res2.n_candidates}cand;{res2.candidates_per_second:,.0f}cand_per_s")
-
-
 def table5_kernel_fusion():
     """Per-kernel Eq. (1) HBM-traffic savings (fused vs layer-by-layer) and
     interpret-mode correctness residual vs the jnp oracle."""
@@ -322,7 +305,6 @@ TABLES = [
     table1_vgg16_flow,
     table2_energy_per_group,
     table3_arch_compare,
-    table4_sweep_throughput,
     table5_kernel_fusion,
     table6_planner,
     table7_resnet_fusion,

@@ -1,7 +1,8 @@
 """JAX's persistent compilation cache, switched on by entry points.
 
 A fresh process pays every XLA compile again (the fleet sweep, the Pallas
-kernels).  Entry points (``chip_smoke.py``, ``benchmarks/*``) call
+kernels).  Entry points (``chip_smoke.py``, ``bench/harness.py``,
+``benchmarks/run.py``, ``benchmarks/bench_zoo.py``) call
 :func:`enable_compile_cache` once at start-up so a second run reads the
 compiled programs back.  Nothing calls it on import, so tests keep the
 cache off.

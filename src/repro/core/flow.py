@@ -4,19 +4,19 @@ For each (hardware configuration x fusion grouping) candidate, estimate the
 four metrics, reject candidates violating the user constraints, and return
 the feasible candidate with minimum energy.  The cross-product is evaluated
 as a single jitted/vmapped XLA program
-(:func:`repro.core.metrics.evaluate_batch_graph`), which is the JAX-native
+(:func:`repro.core.metrics.evaluate_fleet_graph`), which is the JAX-native
 realisation of the paper's exhaustive sweep — the benchmark reports
 candidates/second.  Groupings are boolean cut vectors over the graph's
 edges; chains (``NetworkIR``) are embedded losslessly via
 :func:`repro.core.ir.as_graph`.
 
-Two serving-system moves keep the cold path cheap (``benchmarks/
-bench_fleet.py``): argument shapes are rounded up to power-of-two *shape
-buckets* and evaluated through masked kernels (padded rows exactly inert),
-so distinct graphs share one compiled executable instead of each paying
-XLA compilation per exact ``(L, E, C)`` signature; and :func:`run_fleet`
-stacks many padded graphs along a leading axis to evaluate the whole
-``(G, H, C)`` cross-product — the entire model fleet — in one program.
+Two serving-system moves keep the cold path cheap: argument shapes are
+rounded up to power-of-two *shape buckets* and evaluated through masked
+kernels (padded rows exactly inert), so distinct graphs share one compiled
+executable instead of each paying XLA compilation per exact ``(L, E, C)``
+signature; and :func:`run_fleet` stacks many padded graphs along a leading
+axis to evaluate the whole ``(G, H, C)`` cross-product — the entire model
+fleet — in one program.  :func:`run_flow` is its one-graph form.
 """
 from __future__ import annotations
 
@@ -107,9 +107,9 @@ class FlowResult:
 
 
 # AOT-compiled evaluator executables keyed by (kernel, argument shapes), so
-# a run_flow/run_fleet call executes the sweep exactly once: the first call
-# with a new shape signature pays (and reports) the XLA compile, repeats
-# reuse the executable and report compile_seconds == 0.  The cache is a
+# a run_fleet call executes the sweep exactly once: the first call with a
+# new shape signature pays (and reports) the XLA compile, repeats reuse the
+# executable and report compile_seconds == 0.  The cache is a
 # bounded LRU: a hit refreshes the entry, and at capacity only the
 # least-recently-used executable is evicted (never a wholesale clear, which
 # would drop every hot executable at once).
@@ -170,8 +170,8 @@ def _cache_entry_info(key: tuple) -> dict:
 
 def sweep_cache_stats() -> dict:
     """Executable-cache accounting: {size, hits, misses, evictions,
-    entries}.  ``misses`` counts XLA compilations actually paid — the fleet
-    benchmark asserts a whole multi-model sweep costs exactly one.
+    entries}.  ``misses`` counts XLA compilations actually paid — the tests
+    assert a whole multi-model sweep costs exactly one.
     ``entries`` lists each cached executable's {kernel, mesh_axis,
     device_count}, so the device-layout split of the key space is
     observable (a 1-device sweep and an 8-device sweep are distinct
@@ -480,8 +480,9 @@ def _best_flow_result(
     quarantine: "QuarantineReport | None" = None,
 ) -> FlowResult:
     """Constraint filter + min-energy argmin over one graph's sweep output —
-    the single best-point selection shared by run_flow and run_fleet (so
-    feasibility/tie-break semantics can never drift between them).
+    run_fleet's whole-plane selection, with the tie-break rule
+    (:func:`_pick`) its survivor path shares, so the two paths can never
+    pick differently.
 
     Tie-breaking is deterministic: among equal-energy feasible candidates
     the winner is the lexicographic minimum of (bandwidth, latency, area,
@@ -680,134 +681,30 @@ def run_flow(
     constraints: Constraints = Constraints(),
     groupings: str | np.ndarray = "exhaustive",
     sram_budget_words: float = float("inf"),
-    bucket: bool = True,
     pareto: bool = False,
 ) -> FlowResult:
     """Sweep (hw x grouping), filter by constraints, return min-energy point.
 
-    ``groupings`` is resolved by :func:`groupings_batch`.  A finite
-    ``sram_budget_words`` drops buffer-infeasible groupings *before* the
-    sweep via the batched prefilter
-    (:func:`repro.core.fusion.graph_feasible_mask_batch`), so the XLA
-    program never evaluates candidates the budget would reject anyway.
-
-    With ``bucket=True`` (the default) the ``(L, E, C)`` signature is
-    rounded up to power-of-two shape buckets (floors ``NODE_BUCKET_FLOOR``
-    etc.) and evaluated through the masked kernels — bit-identical metrics
-    (padded rows are exactly inert), but graphs sharing a bucket share one
-    compiled executable instead of each paying the XLA compile.  Bucketing
-    the candidate axis re-pads the prefiltered batch with up to ~2x inert
-    dummy rows (sliced off before the argmin) — microseconds of sweep work
-    traded for skipping whole-seconds recompiles on every distinct
-    surviving-candidate count.  ``bucket=False`` keeps the exact-shape,
-    no-dummy signature (one compile per distinct graph — the benchmark
-    baseline).
-
-    The evaluator is AOT-compiled once per argument-shape signature;
-    ``compile_seconds`` reports the XLA compilation paid by *this* call
-    (0 on an executable-cache hit, the ``fleet.compile`` span) and
-    ``sweep_seconds`` the execution plus the device-to-host fetch of the
-    raw plane (the ``fleet.execute`` and ``fleet.fetch`` spans);
-    ``candidates_per_second`` is candidates over ``sweep_seconds``.
+    A one-graph :func:`run_fleet`: ``groupings`` is resolved by
+    :func:`groupings_batch`, a finite ``sram_budget_words`` drops
+    buffer-infeasible groupings before the sweep, and the graph is padded
+    to its shape bucket, so graphs that share a bucket share one compiled
+    executable.  ``compile_seconds`` is the XLA compile this call paid (0
+    on an executable-cache hit, the ``fleet.compile`` span) and
+    ``sweep_seconds`` the execution plus the device-to-host fetch (the
+    ``fleet.execute`` and ``fleet.fetch`` spans).
 
     ``pareto=True`` additionally extracts the feasible sweep's
     (bandwidth, latency, energy, area) Pareto front into
     ``FlowResult.pareto`` (:class:`ParetoFront`).
     """
-    if config_space is None:
-        config_space = default_config_space()
-    g = as_graph(ir)
-    cuts_batch, provenance = groupings_batch(
-        g, groupings, sram_budget_words=sram_budget_words,
-        with_provenance=True,
-    )
-
-    n_pruned = 0
-    if np.isfinite(sram_budget_words):
-        max_int = fusion.graph_max_intermediate_batch(g, cuts_batch)
-        keep = max_int <= sram_budget_words
-        n_pruned = int(cuts_batch.shape[0] - keep.sum())
-        if not keep.any():
-            # Never return a silently-empty sweep: report the smallest
-            # budget under which at least one offered grouping survives.
-            raise InfeasibleBudgetError(
-                f"{g.name}: no grouping fits the SRAM budget "
-                f"({sram_budget_words:.0f} words; the cheapest offered "
-                f"grouping needs {max_int.min():.0f})",
-                min_feasible_budget_words=float(max_int.min()),
-            )
-        cuts_batch = cuts_batch[keep]
-    C = cuts_batch.shape[0]
-
-    hw_rows = np.stack([c.as_row() for c in config_space])
-    area_consts = M.area_consts_of_space(config_space)
-
-    if bucket:
-        pg = pad_graph(
-            g,
-            n_nodes=bucket_size(g.n_nodes, NODE_BUCKET_FLOOR),
-            n_edges=bucket_size(g.n_edges, EDGE_BUCKET_FLOOR),
-        )
-        args = (
-            pg.feat,
-            pg.esrc,
-            pg.edst,
-            pg.ewords,
-            pg.src_mask,
-            pg.sink_mask,
-            pad_cuts_batch(
-                cuts_batch, pg.n_edges_padded, bucket_size(C, CUT_BUCKET_FLOOR)
-            ),
-            hw_rows,
-            area_consts,
-            pg.node_mask,
-            pg.edge_mask,
-        )
-    else:
-        feat = g.node_features()
-        esrc, edst, ewords = g.edge_arrays()
-        args = (
-            feat,
-            esrc,
-            edst,
-            ewords,
-            g.source_mask,
-            g.sink_mask,
-            cuts_batch,
-            hw_rows,
-            area_consts,
-        )
-    # f64-exactness guard: the bit-identity guarantee assumes every
-    # feature / edge-word entry is an exactly-representable integer f64
-    # (<= 2^53); a corrupted or overflowed table must fail loudly here,
-    # not silently split ulps inside the sweep.
-    M.assert_exact_f64(args[0], what=f"{g.name} feature table")
-    M.assert_exact_f64(args[3], what=f"{g.name} edge words")
-    exe, compile_seconds = _compiled_sweep(M._jit_batch_graph, args)
-    # raw (H, C_b, 5) rows -> (H, C, 4) metrics, padded candidate rows
-    # sliced off before feasibility/argmin
-    raw, sweep_seconds = _run_sweep(exe, args)
-    out = M.compose_metrics(raw, hw_rows)[:, :C]
-    # Finite guard: quarantine poisoned raw cells before any selection.
-    poison = M.poison_mask(raw)[:, :C]
-    quarantine = None
-    if poison.any():
-        quarantine = QuarantineReport(
-            cells=_quarantine_cells(raw[:, :C], poison, graph=0)
-        )
-    else:
-        poison = None
-    n_cand = out.shape[0] * C
-    return _best_flow_result(
-        out, cuts_batch, g, config_space, constraints,
-        n_pruned=n_pruned,
-        compile_seconds=compile_seconds,
-        sweep_seconds=sweep_seconds,
-        candidates_per_second=n_cand / max(sweep_seconds, 1e-9),
-        search_engine=provenance,
+    fleet = run_fleet(
+        [ir], config_space=config_space, constraints=constraints,
+        groupings=groupings, sram_budget_words=sram_budget_words,
         pareto=pareto,
-        poison=poison,
-        quarantine=quarantine,
+    )
+    return dataclasses.replace(
+        fleet.results[0], compile_seconds=fleet.compile_seconds
     )
 
 
@@ -880,13 +777,15 @@ def run_fleet(
     """Sweep many graphs' (hw x grouping) cross-products in ONE XLA program.
 
     Every graph is zero-padded to the fleet-wide ``(L, E, C)`` bucket
-    (power-of-two, same floors as :func:`run_flow`), stacked along a new
-    leading axis, and evaluated by a single vmapped executable
+    (power-of-two, never below ``NODE_BUCKET_FLOOR`` etc.), stacked along
+    a new leading axis, and evaluated by a single vmapped executable
     (:func:`repro.core.metrics.evaluate_fleet_graph`) — the whole fleet
     pays at most one XLA compile (0 on a bucket-cache hit), which is the
     serving-system move the per-model cold path cannot make.  Per-graph
-    metrics are bit-identical to :func:`run_flow` (padded rows are exactly
-    inert and sliced off before feasibility/argmin; asserted in tests).
+    metrics are bit-identical to the unpadded sweep
+    (:func:`repro.core.metrics.evaluate_batch_graph`; padded rows are
+    exactly inert and sliced off before feasibility/argmin; asserted in
+    tests).
 
     ``groupings`` / ``sram_budget_words`` / ``constraints`` apply to every
     graph — except that ``groupings`` may also be a *sequence* of explicit

@@ -24,8 +24,7 @@ batches — there is no per-candidate Python on any search path:
 
 The scalar functions (``is_valid_cuts``, ``graph_max_intermediate``,
 ``bandwidth_ref``, the ``_*_scalar`` search variants) are kept as the
-oracles; tests assert the batched kernels match them bit-for-bit, and
-``benchmarks/bench_search.py`` measures the speedup against them.
+oracles; tests assert the batched kernels match them bit-for-bit.
 
 Strategies, all returning cut vectors compatible with
 :mod:`repro.core.metrics`:
@@ -104,9 +103,9 @@ ENUM_CHUNK_ROWS = 1 << 17
 # Below this many bit patterns the per-pattern scalar filter beats the
 # batched pipeline's cold setup (graph-array build + batch labelling +
 # vectorised peeling all cost ~1 ms flat; the scalar filter is ~20 us per
-# pattern), so tiny graphs dispatch straight to the preserved scalar path
-# — BENCH_search.json showed 0.22x/0.4x *cold* "speedups" on the
-# 16-candidate residual block before this threshold existed.
+# pattern), so tiny graphs dispatch straight to the preserved scalar path:
+# on the 16-candidate residual block the batched pipeline's cold call lost
+# to the scalar filter before this threshold existed.
 SMALL_ENUM_PATTERNS = 64
 # Frontier-DP caps: beyond this frontier width (or live-state count) the
 # exact DP abandons the attempt and `optimal_cuts` falls back to beam
@@ -298,8 +297,8 @@ def enumerate_valid_edge_cuts(
 
 
 def _enumerate_valid_edge_cuts_scalar(g: GraphIR) -> np.ndarray:
-    """The PR 1 per-pattern filter — kept as the enumeration oracle and the
-    benchmark baseline (``benchmarks/bench_search.py``)."""
+    """The per-pattern filter — kept as the enumeration oracle and
+    the small-graph path (up to ``SMALL_ENUM_PATTERNS`` patterns)."""
     if g.is_chain:
         return enumerate_cuts(len(g.nodes))
     E = g.n_edges
@@ -372,8 +371,7 @@ def graph_feasible_mask_batch(
     g: GraphIR, cuts_batch: np.ndarray, sram_budget_words: float
 ) -> np.ndarray:
     """(C,) bool — graph analog of :func:`feasible_mask_batch`, used by the
-    search strategies and as the SRAM prefilter in
-    :func:`repro.core.flow.run_flow`."""
+    search strategies."""
     return graph_max_intermediate_batch(g, cuts_batch) <= sram_budget_words
 
 

@@ -2,7 +2,7 @@
 
 Importable from production code paths (the service accepts any duck-typed
 ``faults`` object), but shipped under ``repro.testing`` because its only
-in-repo consumers are the chaos tests and ``benchmarks/bench_serve.py``.
+in-repo consumers are the chaos tests.
 """
 from .faults import (
     FaultInjector,

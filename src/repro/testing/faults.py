@@ -19,7 +19,7 @@ Two halves:
   prove correctness is cache-independent).
 
 :func:`chaos_requests` composes both into a reproducible mixed request
-stream for the chaos tests and ``benchmarks/bench_serve.py``.
+stream for the chaos tests.
 """
 from __future__ import annotations
 

@@ -99,9 +99,9 @@ def test_cost_model_notes_claims() -> None:
 def test_architecture_doc_names_real_paths() -> None:
     arch = DOC.with_name("ARCHITECTURE.md").read_text()
     root = DOC.parents[1]
-    for rel in ("benchmarks/bench_search.py", "benchmarks/bench_fleet.py",
-                "benchmarks/bench_shard.py", "benchmarks/bench_serve.py",
-                "benchmarks/bench_zoo.py", "docs/OP_COVERAGE.md"):
+    for rel in ("bench/run.py", "bench/drivers.py", "benchmarks/run.py",
+                "tests/test_multidevice.py", "benchmarks/bench_zoo.py",
+                "docs/OP_COVERAGE.md"):
         assert rel.rsplit("/", 1)[-1] in arch, rel
         assert (root / rel).exists(), rel
 
@@ -173,7 +173,7 @@ def test_service_doc_names_real_paths_and_knobs() -> None:
     root = SERVICE_DOC.parents[1]
     for rel in ("tests/test_journal.py", "tests/test_journal_property.py",
                 "tests/test_docs.py", "examples/serve_lm.py",
-                "benchmarks/bench_serve.py"):
+                "bench/traffic/serve.json"):
         assert rel in text, rel
         assert (root / rel).exists(), rel
     # every knob the doc mentions is a real constructor parameter
@@ -283,7 +283,7 @@ def test_resilience_doc_names_real_symbols_and_paths() -> None:
     text = RESILIENCE_DOC.read_text()
     root = RESILIENCE_DOC.parents[1]
     for rel in ("tests/test_salvage.py", "tests/test_salvage_property.py",
-                "tests/test_faults.py", "benchmarks/bench_shard.py"):
+                "tests/test_faults.py", "tests/test_multidevice.py"):
         assert rel in text, rel
         assert (root / rel).exists(), rel
     from repro.core import flow, metrics

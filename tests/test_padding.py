@@ -3,8 +3,8 @@
 Locks the PR's core claim: zero-padding a graph's evaluator arrays to a
 shape bucket and evaluating through the masked kernels is **bit-identical**
 to the unpadded batch path and the scalar ``*_ref`` oracles, for all four
-metrics and the SRAM feasibility mask — so `run_flow(bucket=True)` and
-`run_fleet` change compile economics, never results.
+metrics and the SRAM feasibility mask — so the bucketed `run_fleet` (and
+`run_flow`, its one-graph form) changes compile economics, never results.
 """
 import numpy as np
 import pytest
@@ -131,14 +131,19 @@ def test_padded_bit_identical_on_workloads(name, g):
 
 
 def test_run_flow_bucketed_equals_unbucketed():
+    # run_flow sweeps the bucketed (padded) program; its pick is the
+    # argmin of the exact-shape sweep over the same cut batch: least
+    # energy, ties to the least (bandwidth, latency, area, cut index).
     for g in (resnet18_ir(), as_graph(mlp_block_graph())):
         b = flow.run_flow(g, config_space=[HW], constraints=RELAXED,
                           groupings="search")
-        u = flow.run_flow(g, config_space=[HW], constraints=RELAXED,
-                          groupings="search", bucket=False)
-        assert b.best_metrics == u.best_metrics
-        assert np.array_equal(b.best_cuts, u.best_cuts)
-        assert b.n_candidates == u.n_candidates  # padded rows not counted
+        cuts = flow.groupings_batch(g, "search")
+        u = _eval_unpadded(g, cuts, HW.as_row()[None],
+                           M.area_consts_of_space([HW]))[0]  # (C, 4)
+        c = np.lexsort((u[:, 3], u[:, 1], u[:, 0], u[:, 2]))[0]
+        assert b.best_metrics == M.Metrics(*map(float, u[c]))
+        assert np.array_equal(b.best_cuts, cuts[c])
+        assert b.n_candidates == u.shape[0]  # padded rows not counted
 
 
 # ---------------------------------------------------------------------------

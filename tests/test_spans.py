@@ -156,9 +156,10 @@ def test_run_flow_emits_the_sweep_stages():
     t0 = time.perf_counter()
     res = flow.run_flow(residual_block_ir(), config_space=GRID,
                         constraints=RELAXED)
-    names = sorted(r.name for r in spans.records(t_from=t0))
-    assert names == ["fleet.compile", "fleet.execute", "fleet.fetch"]
-    by = {r.name: r for r in spans.records(t_from=t0)}
+    [(root, kids)] = _calls(t0)  # run_flow is a one-graph run_fleet
+    assert _names(kids) == sorted(STAGES + ("fleet.compile",))
+    assert all(r.parent_id == root.span_id for r in kids)
+    by = {r.name: r for r in kids}
     assert res.compile_seconds == by["fleet.compile"].seconds
     assert res.sweep_seconds == (by["fleet.execute"].seconds
                                  + by["fleet.fetch"].seconds)
