@@ -450,7 +450,39 @@ class _Tracer:
             self.producer[o] = p
 
     # ---- driver ------------------------------------------------------------
-    def run(self, jaxpr) -> GraphIR:
+    def run(self, steps) -> GraphIR:
+        """Lower ``steps``, (jaxpr, activation invars) pairs, in order into
+        one graph.  The first step's activation invars are graph inputs,
+        each its own source; a later step's take the previous step's
+        outputs, in order (one sublayer's residual stream into the next).
+        One jaxpr may stand for several steps: each walk rebinds every
+        activation var before reading it, so it lowers as copies would."""
+        outs = None
+        for jaxpr, act_vars in steps:
+            for i, v in enumerate(act_vars):
+                self.producer[v] = v if outs is None else outs[i]
+            self._walk(jaxpr)
+            outs = [None if isinstance(v, jex_core.Literal)
+                    else self.producer.get(v) for v in jaxpr.outvars]
+        live = self._live_nodes(outs or ())
+        if not live:
+            raise UnsupportedOpError(f"{self.name}: no layers traced")
+        new_id = {old: new for new, old in enumerate(live)}
+        specs = []
+        for old in live:
+            spec = self.nodes[old].spec
+            if len(live) < len(self.nodes):  # renumbered: rename to match
+                stem = spec.name.rstrip("0123456789")
+                spec = dataclasses.replace(spec, name=f"{stem}{new_id[old]}")
+            specs.append(spec)
+        edges = tuple(
+            EdgeSpec(new_id[src], new_id[dst], words)
+            for dst in live
+            for src, words in sorted(self.nodes[dst].inputs.items())
+        )
+        return GraphIR(self.name, tuple(specs), edges)
+
+    def _walk(self, jaxpr) -> None:
         self._use_count: dict[Any, int] = {}
         for eqn in jaxpr.eqns:
             for v in eqn.invars:
@@ -496,34 +528,15 @@ class _Tracer:
                 self.eqn_scan(eqn, act_in)
             else:
                 self.eqn_default(eqn, act_in)
-        live = self._live_nodes(jaxpr.outvars)
-        if not live:
-            raise UnsupportedOpError(f"{self.name}: no layers traced")
-        new_id = {old: new for new, old in enumerate(live)}
-        specs = []
-        for old in live:
-            spec = self.nodes[old].spec
-            if len(live) < len(self.nodes):  # renumbered: rename to match
-                stem = spec.name.rstrip("0123456789")
-                spec = dataclasses.replace(spec, name=f"{stem}{new_id[old]}")
-            specs.append(spec)
-        edges = tuple(
-            EdgeSpec(new_id[src], new_id[dst], words)
-            for dst in live
-            for src, words in sorted(self.nodes[dst].inputs.items())
-        )
-        return GraphIR(self.name, tuple(specs), edges)
 
-    def _live_nodes(self, outvars) -> list[int]:
-        """Ids (ascending) of the nodes from which a traced output can be
-        reached.  ``make_jaxpr`` keeps dead equations (a loss term the
-        function computes and drops); a node that feeds no output would
-        only add a sink write to every grouping, so it is left out."""
+    def _live_nodes(self, outs) -> list[int]:
+        """Ids (ascending) of the nodes from which a traced output, given
+        by its source, can be reached.  ``make_jaxpr`` keeps dead equations
+        (a loss term the function computes and drops); a node that feeds no
+        output would only add a sink write to every grouping, so it is left
+        out."""
         stack = []
-        for v in outvars:
-            if isinstance(v, jex_core.Literal) or v not in self.producer:
-                continue
-            p = self.producer[v]
+        for p in outs:
             stack.extend(p if isinstance(p, tuple) else
                          [p] if isinstance(p, int) else [])
         live: set[int] = set()
@@ -599,8 +612,24 @@ def _trace(fn, args, name, activation_argnums, fold_pool, names,
         if activation_argnums is None
         else {a % len(args) for a in activation_argnums}
     )
+    closed = _make_jaxpr(fn, args, name)
+    act_vars = []
+    invars = iter(closed.jaxpr.invars)
+    for i, arg in enumerate(args):
+        for _ in jax.tree_util.tree_leaves(arg):
+            v = next(invars)
+            if i in nums:
+                act_vars.append(v)
+    tr = _Tracer(name=name, fold_pool=fold_pool, batch_groups=batch_groups)
+    g = _lower(tr, [(closed.jaxpr, act_vars)])
+    if names is not None:
+        g = rename_nodes(g, names)
+    return g
+
+
+def _make_jaxpr(fn, args, name):
     try:
-        closed = jax.make_jaxpr(fn)(*args)
+        return jax.make_jaxpr(fn)(*args)
     except (UnsupportedOpError, GraphValidationError):
         raise
     except Exception as e:
@@ -611,32 +640,24 @@ def _trace(fn, args, name, activation_argnums, fold_pool, names,
             f"{name}: fn is not traceable to a jaxpr "
             f"({type(e).__name__}: {e})"
         ) from e
-    tr = _Tracer(name=name, fold_pool=fold_pool, batch_groups=batch_groups)
-    invars = iter(closed.jaxpr.invars)
-    for i, arg in enumerate(args):
-        leaves = jax.tree_util.tree_leaves(arg)
-        for _ in leaves:
-            v = next(invars)
-            if i in nums:
-                tr.producer[v] = v  # each input var is its own source
+
+
+def _lower(tr: _Tracer, steps) -> GraphIR:
     # Lowering must fail *typed*: an unlowerable jaxpr is an
     # UnsupportedOpError and a lowered-but-invalid IR a
     # GraphValidationError — never a raw KeyError/IndexError from a
     # degenerate primitive the lowering rules did not anticipate (the
     # contract the service admission path and the fuzz tests rely on).
     try:
-        g = tr.run(closed.jaxpr)
+        return tr.run(steps)
     except (GraphValidationError, UnsupportedOpError):
         raise
     except (KeyError, IndexError, AttributeError, TypeError,
             ZeroDivisionError, AssertionError) as e:
         raise UnsupportedOpError(
-            f"{name}: jaxpr is not lowerable to the layer abstraction "
+            f"{tr.name}: jaxpr is not lowerable to the layer abstraction "
             f"({type(e).__name__}: {e})"
         ) from e
-    if names is not None:
-        g = rename_nodes(g, names)
-    return g
 
 
 def rename_nodes(g: GraphIR, names: Sequence[str]) -> GraphIR:
@@ -818,23 +839,38 @@ def transformer_graph(cfg, *, seq_len: int = 512,
     pattern period, so jamba's 1:7 attn:mamba interleave and llama4's
     alternating dense/MoE both appear once).  ``batch_groups`` is
     :func:`trace`'s: the attention and routing products at the MACs of one
-    product per head or routing group."""
+    product per head or routing group.
+
+    The period is traced once per distinct (mixer, is_moe) sublayer kind,
+    a one-sublayer ``block_forward`` each, and lowered sublayer by
+    sublayer in the period's order, each sublayer's residual input the
+    previous one's output: the graph is the one :func:`trace` gives of the
+    whole period's ``block_forward``, node for node and edge for edge.
+    Runs in a ``frontend.trace`` span (``work``: the node count) with one
+    ``frontend.jaxpr`` child per kind (its param specs and jaxpr; ``work``:
+    the sublayers of the period it stands for)."""
     from ..configs.base import RunConfig
     from ..models import transformer as T
 
     count = cfg.pattern_period if n_sublayers is None else n_sublayers
     kinds = cfg.sublayer_kinds(0, count)
     seq_len = _zoo_seq_len(cfg, seq_len)
-    params = T.sublayer_param_specs(cfg, kinds)
+    name = name or f"{cfg.name}.block"
+    x = _sds(1, seq_len, cfg.d_model)
     rc = RunConfig()
-    return trace(
-        lambda p, x: T.block_forward(p, x, cfg, kinds, rc=rc,
-                                     attn_impl="reference"),
-        params,
-        _sds(1, seq_len, cfg.d_model),
-        name=name or f"{cfg.name}.block",
-        batch_groups=batch_groups,
-    )
+    with spans.span("frontend.trace") as sp:
+        jaxprs = {}
+        for kind in dict.fromkeys(kinds):
+            with spans.span("frontend.jaxpr", work=kinds.count(kind)):
+                params = T.sublayer_param_specs(cfg, (kind,))
+                jaxprs[kind] = _make_jaxpr(
+                    lambda p, x, kind=kind: T.block_forward(
+                        p, x, cfg, (kind,), rc=rc, attn_impl="reference"),
+                    (params, x), name).jaxpr
+        tr = _Tracer(name=name, fold_pool=False, batch_groups=batch_groups)
+        g = _lower(tr, [(jaxprs[k], jaxprs[k].invars[-1:]) for k in kinds])
+        sp.work = g.n_nodes
+    return g
 
 
 def mamba_graph(cfg, *, seq_len: int = 512, chunks: int = 1,

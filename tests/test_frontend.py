@@ -421,3 +421,63 @@ def test_batch_groups_prices_one_product_per_head(batch_groups):
     assert a.macs == H * S * S * hd * (1 if batch_groups else H)
     assert a.out_words == H * S * S
     assert [e.words for e in g.edges] == [S * H * hd, S * H * hd]
+
+
+# Each config whose period repeats a sublayer kind, and Mixtral (a period
+# of one), at two prefill lengths that tile its routing groups.
+_PERIOD_CASES = [
+    ("jamba2-mini", 4096), ("jamba2-mini", 131072),
+    ("jamba-1.5-large-398b", 1024), ("jamba-1.5-large-398b", 4096),
+    ("gemma3-27b", 1024), ("gemma3-27b", 4096),
+    ("llama4-maverick-400b-a17b", 1024), ("llama4-maverick-400b-a17b", 4096),
+    ("mixtral-8x7b", 512), ("mixtral-8x7b", 4096),
+]
+
+
+@pytest.mark.parametrize("batch_groups", [False, True])
+@pytest.mark.parametrize("config,seq_len", _PERIOD_CASES)
+def test_period_traced_per_kind_equals_whole_period_trace(
+        config, seq_len, batch_groups):
+    """``transformer_graph`` traces each distinct sublayer kind once and
+    lowers the period sublayer by sublayer; the oracle traces the whole
+    period's ``block_forward`` in one jaxpr.  Every node's name and spec
+    and every edge agree, in order."""
+    from repro.configs import REGISTRY
+    from repro.configs.base import RunConfig
+    from repro.models import transformer as T
+
+    cfg = REGISTRY[config]
+    kinds = cfg.sublayer_kinds(0, cfg.pattern_period)
+    want = F.trace(
+        lambda p, x: T.block_forward(p, x, cfg, kinds, rc=RunConfig(),
+                                     attn_impl="reference"),
+        T.sublayer_param_specs(cfg, kinds), F._sds(1, seq_len, cfg.d_model),
+        name=f"{cfg.name}.block", batch_groups=batch_groups)
+    got = F.transformer_graph(cfg, seq_len=seq_len,
+                              batch_groups=batch_groups)
+    assert [n.name for n in got.nodes] == [n.name for n in want.nodes]
+    assert got.nodes == want.nodes
+    assert got.edges == want.edges
+    assert got == want
+
+
+@pytest.mark.parametrize("config,seq_len,works", [
+    ("jamba2-mini", 4096, [3, 4, 1]),  # (mamba, dense), (mamba, moe), attn
+    ("mixtral-8x7b", 512, [1]),
+])
+def test_period_trace_records_one_jaxpr_span_per_kind(config, seq_len, works):
+    import time
+
+    from repro.configs import REGISTRY
+    from repro.core import spans
+
+    cfg = REGISTRY[config]
+    t0 = time.perf_counter()
+    g = F.transformer_graph(cfg, seq_len=seq_len)
+    (root,) = spans.records("frontend.trace", t0)
+    assert root.work == g.n_nodes
+    kids = [r for r in spans.records(t_from=t0)
+            if r.parent_id == root.span_id]
+    assert [r.name for r in kids] == ["frontend.jaxpr"] * len(works)
+    assert [r.work for r in kids] == works
+    assert sum(r.seconds for r in kids) < root.seconds
